@@ -193,9 +193,15 @@ def bench_telemetry(*, n_nodes: int = 8, steps: int = 160, chunk: int = 8,
 ROWS: list[dict] = []  # every csv_row also lands here for --json export
 
 
-def csv_row(name: str, us: float, derived: str) -> None:
+def csv_row(name: str, us: float, derived: str, *,
+            platform: str | None = None) -> None:
+    """Print one CSV row and keep it for ``--json``.  ``platform`` is the
+    device the row was measured on; ``None`` means this process's."""
     print(f"{name},{us:.1f},{derived}")
-    row = {"name": name, "us_per_call": round(us, 1)}
+    if platform is None:
+        import jax
+        platform = jax.devices()[0].platform
+    row = {"name": name, "us_per_call": round(us, 1), "platform": platform}
     for part in derived.split(","):
         k, _, v = part.partition("=")
         if _:

@@ -52,6 +52,8 @@ def _node_digests(params) -> dict:
 
 
 def worker(cfg: dict) -> None:
+    # a CPU study by design: pin the platform so it never takes the chip
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                                f"{cfg['devices_per_proc']}")
     import jax

@@ -26,15 +26,19 @@ packed one-pass kernels).  Each row carries:
 Wall time is reported for completeness but never gated.
 """
 import json
+import os
 import sys
 import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+# a CPU study by design: pin the platform so it never takes the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-from repro.core import optim, topology, transforms
-from repro.train import DecentralizedTrainer, run_training
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from repro.core import optim, topology, transforms  # noqa: E402
+from repro.train import DecentralizedTrainer, run_training  # noqa: E402
 
 SPEC = json.loads(sys.argv[1])
 
@@ -132,7 +136,9 @@ def main():
             "xla_bytes_accessed": _xla_bytes(opt, st.params, w),
             "mismatches": mismatches,
         })
-    print("KERNEL_ROWS " + json.dumps(rows))
+    platform = jax.devices()[0].platform
+    print("KERNEL_ROWS " + json.dumps(
+        [dict(r, platform=platform) for r in rows]))
 
 
 if __name__ == "__main__":

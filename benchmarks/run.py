@@ -57,6 +57,25 @@ from .common import ROWS, bench_loop, bench_telemetry, csv_row, \
 BENCH_SCHEMA_VERSION = 2
 
 
+def _worker_rows(module: str, marker: str, spec: dict) -> list[dict]:
+    """Run ``benchmarks.<module>`` in a child process (each worker pins
+    itself to the CPU: forced host devices or interpret-mode kernels) and
+    return the rows of its ``<marker> <json list>`` line."""
+    import sys
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src" + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    res = subprocess.run(
+        [sys.executable, "-m", f"benchmarks.{module}", json.dumps(spec)],
+        capture_output=True, text=True, timeout=3600, env=env,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    lines = [ln for ln in res.stdout.splitlines()
+             if ln.startswith(marker + " ")]
+    if not lines:
+        raise RuntimeError(f"{module} failed: {res.stderr[-2000:]}")
+    return json.loads(lines[0][len(marker) + 1:])
+
+
 def _git_rev() -> str:
     try:
         out = subprocess.run(
@@ -179,9 +198,6 @@ def topology(quick=False):
     subprocess because the forced host-device count must precede jax init.
     ``bytes_ratio`` is dense/sparse point-to-point model messages per gossip
     step — the acceptance row is social32 >= 2x."""
-    import subprocess
-    import sys
-
     combos = [["ring", 8], ["ring", 16], ["ring", 32],
               ["torus", 8], ["torus", 16], ["torus", 32],
               ["exp", 8], ["exp", 16], ["exp", 32],
@@ -191,22 +207,11 @@ def topology(quick=False):
     spec = {"devices": max(c[1] for c in combos),
             "dim": 16384 if quick else 65536,
             "reps": 15 if quick else 20, "combos": combos}
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    res = subprocess.run(
-        [sys.executable, "-m", "benchmarks.topo_worker", json.dumps(spec)],
-        capture_output=True, text=True, timeout=3600, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    lines = [ln for ln in res.stdout.splitlines()
-             if ln.startswith("TOPO_ROWS ")]
-    if not lines:
-        raise RuntimeError(f"topo_worker failed: {res.stderr[-2000:]}")
-    for r in json.loads(lines[0][len("TOPO_ROWS "):]):
+    for r in _worker_rows("topo_worker", "TOPO_ROWS", spec):
         tag = f"topology/{r['label']}"
         csv_row(f"{tag}/dense", r["us_dense"],
                 f"mix_per_s={1e6 / r['us_dense']:.1f},"
-                f"msgs={r['msgs_dense']:.0f}")
+                f"msgs={r['msgs_dense']:.0f}", platform=r["platform"])
         csv_row(
             f"{tag}/sparse", r["us_sparse"],
             f"mix_per_s={1e6 / r['us_sparse']:.1f},"
@@ -214,7 +219,8 @@ def topology(quick=False):
             f"bytes_ratio={r['bytes_ratio']:.1f},"
             f"rounds={r['rounds']},phases={r['phases']},"
             f"speedup={r['us_dense'] / r['us_sparse']:.2f},"
-            f"fallback={'dense' if r['fallback_dense'] else 'sparse'}")
+            f"fallback={'dense' if r['fallback_dense'] else 'sparse'}",
+            platform=r["platform"])
 
 
 def runtime(quick=False):
@@ -228,29 +234,15 @@ def runtime(quick=False):
     ring-16, sharded state bytes constant in n, and overlap steps/s >=
     sharded at ring-16/32.  Runs in a subprocess because the forced
     host-device count must precede jax init."""
-    import subprocess
-    import sys
-
     ns = [8, 16, 32]      # ring-32 also feeds the overlap>=sharded CI gate
     spec = {"devices": max(ns), "ns": ns,
             "steps": 16 if quick else 32, "chunk": 8,
             "batch": 8, "n_data": 1024 if quick else 2048}
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    res = subprocess.run(
-        [sys.executable, "-m", "benchmarks.runtime_worker", json.dumps(spec)],
-        capture_output=True, text=True, timeout=3600, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    lines = [ln for ln in res.stdout.splitlines()
-             if ln.startswith("RUNTIME_ROWS ")]
-    if not lines:
-        raise RuntimeError(f"runtime_worker failed: {res.stderr[-2000:]}")
-    for r in json.loads(lines[0][len("RUNTIME_ROWS "):]):
+    for r in _worker_rows("runtime_worker", "RUNTIME_ROWS", spec):
         csv_row(f"runtime/{r['runtime']}/ring{r['n']}", r["us_per_step"],
                 f"steps_per_s={r['steps_per_s']:.1f},"
                 f"state_bytes={r['state_bytes_per_device']},"
-                f"loss={r['loss']:.4f}")
+                f"loss={r['loss']:.4f}", platform=r["platform"])
 
 
 def scenario(quick=False):
@@ -264,31 +256,17 @@ def scenario(quick=False):
     gossip win; with physical cores behind the 8 devices the n=256 ratio
     rises toward the device count), eval_loss(QG) < eval_loss(DSGDm), and
     max_abs_param_diff == 0."""
-    import subprocess
-    import sys
-
     spec = {"devices": 8, "perf_ns": [256, 1024],
             "perf_steps": 16 if quick else 32, "perf_chunk": 8,
             "big_steps": 25 if quick else 50, "big_chunk": 5,
             "det_steps": 6 if quick else 12, "timed_reps": 2}
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    res = subprocess.run(
-        [sys.executable, "-m", "benchmarks.scenario_worker",
-         json.dumps(spec)],
-        capture_output=True, text=True, timeout=3600, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    lines = [ln for ln in res.stdout.splitlines()
-             if ln.startswith("SCENARIO_ROWS ")]
-    if not lines:
-        raise RuntimeError(f"scenario_worker failed: {res.stderr[-2000:]}")
-    for r in json.loads(lines[0][len("SCENARIO_ROWS "):]):
+    for r in _worker_rows("scenario_worker", "SCENARIO_ROWS", spec):
         derived = ",".join(f"{k}={v:.6g}" if isinstance(v, float)
                            else f"{k}={v}"
                            for k, v in r.items()
-                           if k not in ("tag", "us_per_step"))
-        csv_row(f"scenario/{r['tag']}", r["us_per_step"], derived)
+                           if k not in ("tag", "us_per_step", "platform"))
+        csv_row(f"scenario/{r['tag']}", r["us_per_step"], derived,
+                platform=r["platform"])
 
 
 def loop(quick=False):
@@ -358,24 +336,10 @@ def serve(quick=False):
     refuses to report throughput unless the engine's greedy tokens are
     bit-identical to the baseline; the CI gate holds
     ``tokens_per_s(engine) >= 1.5 x tokens_per_s(sequential)``."""
-    import subprocess
-    import sys
-
     spec = {"arch": "tinyllama-1.1b", "requests": 12 if quick else 30,
             "max_new": 16, "n_slots": 8, "page_size": 16,
             "prefill_chunk": 16, "max_len": 64}
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    res = subprocess.run(
-        [sys.executable, "-m", "benchmarks.serve_worker", json.dumps(spec)],
-        capture_output=True, text=True, timeout=3600, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    lines = [ln for ln in res.stdout.splitlines()
-             if ln.startswith("SERVE_ROWS ")]
-    if not lines:
-        raise RuntimeError(f"serve_worker failed: {res.stderr[-2000:]}")
-    rows = json.loads(lines[0][len("SERVE_ROWS "):])
+    rows = _worker_rows("serve_worker", "SERVE_ROWS", spec)
     by_mode = {r["mode"]: r for r in rows}
     ratio = (by_mode["engine"]["tokens_per_s"]
              / by_mode["sequential"]["tokens_per_s"])
@@ -388,13 +352,11 @@ def serve(quick=False):
                       f"speedup={ratio:.2f}")
         csv_row(f"serve/{r['arch']}/{r['mode']}",
                 r["wall_s"] / r["tokens"] * 1e6,
-                f"tokens_per_s={r['tokens_per_s']:.1f}" + extra)
+                f"tokens_per_s={r['tokens_per_s']:.1f}" + extra,
+                platform=r["platform"])
 
 
 def kernels(quick=False):
-    import subprocess
-    import sys
-
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops, ref
@@ -406,19 +368,7 @@ def kernels(quick=False):
     # quantum charged to the fused side stays <2% of the byte model
     spec = {"method": "qg_dsgdm", "n": 8, "steps": 8 if quick else 20,
             "d": 512, "c": 128}
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    res = subprocess.run(
-        [sys.executable, "-m", "benchmarks.kernels_worker",
-         json.dumps(spec)],
-        capture_output=True, text=True, timeout=3600, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    lines = [ln for ln in res.stdout.splitlines()
-             if ln.startswith("KERNEL_ROWS ")]
-    if not lines:
-        raise RuntimeError(f"kernels_worker failed: {res.stderr[-2000:]}")
-    rows = json.loads(lines[0][len("KERNEL_ROWS "):])
+    rows = _worker_rows("kernels_worker", "KERNEL_ROWS", spec)
     by_mode = {r["mode"]: r for r in rows}
     ratio = (by_mode["fused"]["bytes_moved_per_step"]
              / by_mode["unfused"]["bytes_moved_per_step"])
@@ -428,7 +378,7 @@ def kernels(quick=False):
         if r["mode"] == "fused":
             extra += f",bytes_ratio={ratio:.3f}"
         csv_row(f"kernels/chain_{r['method']}_ring{r['n']}/{r['mode']}",
-                r["us_per_step"], extra)
+                r["us_per_step"], extra, platform=r["platform"])
 
     key = jax.random.PRNGKey(0)
     reps = 3 if quick else 10
@@ -523,7 +473,8 @@ def roofline(quick=False):
             f"bottleneck={rt['bottleneck']},compute_s={rt['compute_s']:.4f},"
             f"memory_s={rt['memory_s']:.4f},"
             f"collective_s={rt['collective_s']:.4f},"
-            f"useful_flops={rec.get('useful_flops_ratio', 0):.3f}")
+            f"useful_flops={rec.get('useful_flops_ratio', 0):.3f}",
+            platform=rec.get("platform", "unknown"))
 
 
 TABLES = {
@@ -560,6 +511,8 @@ def main(argv=None) -> None:
                          "--json row (CI passes its run date)")
     args = ap.parse_args(argv)
     names = [args.only] if args.only else list(TABLES)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for n in names:
         TABLES[n](quick=args.quick)

@@ -42,6 +42,8 @@ import os
 import sys
 
 SPEC = json.loads(sys.argv[1])
+# a CPU study by design: pin the platform so it never takes the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                            f"{SPEC['devices']}")
 
@@ -134,7 +136,9 @@ def main() -> None:
                          "state_bytes_per_device":
                              ctx["state_bytes_per_device"],
                          "loss": ctx["loss"]})
-    print("RUNTIME_ROWS " + json.dumps(rows))
+    platform = jax.devices()[0].platform
+    print("RUNTIME_ROWS " + json.dumps(
+        [dict(r, platform=platform) for r in rows]))
 
 
 if __name__ == "__main__":

@@ -28,6 +28,8 @@ import os
 import sys
 
 SPEC = json.loads(sys.argv[1])
+# a CPU study by design: pin the platform so it never takes the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                            f"{SPEC['devices']}")
 
@@ -126,7 +128,9 @@ def main() -> None:
     for method in ("dsgdm_n", "qg_dsgdm_n"):
         rows.append(bench_method(method))
     rows.append(bench_determinism())
-    print("SCENARIO_ROWS " + json.dumps(rows))
+    platform = jax.devices()[0].platform
+    print("SCENARIO_ROWS " + json.dumps(
+        [dict(r, platform=platform) for r in rows]))
 
 
 if __name__ == "__main__":

@@ -24,17 +24,21 @@ sequential baseline before any timing is reported — the throughput gate
 the outputs match.
 """
 import json
+import os
 import sys
 import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+# a CPU study by design: pin the platform so it never takes the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-from repro.configs import get_config
-from repro.models import transformer as tf
-from repro.serve import ServeEngine, sequential_generate
-from repro.serve.__main__ import make_requests
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from repro.configs import get_config  # noqa: E402
+from repro.models import transformer as tf  # noqa: E402
+from repro.serve import ServeEngine, sequential_generate  # noqa: E402
+from repro.serve.__main__ import make_requests  # noqa: E402
 
 SPEC = json.loads(sys.argv[1])
 
@@ -100,7 +104,9 @@ def main():
          "p95_token_latency_s": wall_seq / n_tok,
          "mismatches": mismatches},
     ]
-    print("SERVE_ROWS " + json.dumps(rows))
+    platform = jax.devices()[0].platform
+    print("SERVE_ROWS " + json.dumps(
+        [dict(r, platform=platform) for r in rows]))
 
 
 if __name__ == "__main__":

@@ -20,6 +20,8 @@ import os
 import sys
 
 SPEC = json.loads(sys.argv[1])
+# a CPU study by design: pin the platform so it never takes the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                            f"{SPEC['devices']}")
 
@@ -68,7 +70,9 @@ def main() -> None:
                             / max(sparse.messages_per_step(), 1e-9)),
             "us_dense": us_dense, "us_sparse": us_sparse,
         })
-    print("TOPO_ROWS " + json.dumps(rows))
+    platform = jax.devices()[0].platform
+    print("TOPO_ROWS " + json.dumps(
+        [dict(r, platform=platform) for r in rows]))
 
 
 if __name__ == "__main__":

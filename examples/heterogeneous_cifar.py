@@ -70,14 +70,17 @@ def parse_args():
 def main():
     args = parse_args()
     if args.runtime == "sharded":
-        # must precede the first jax import: the sharded backend needs one
-        # (host) device per node to carry the mesh node axis (APPEND so a
-        # pre-existing XLA_FLAGS value keeps its other flags)
+        # a CPU study: one forced host device per node carries the mesh node
+        # axis (on chips, use `python chip_smoke.py --chips 4`).  Must
+        # precede the first jax import; APPEND so a pre-existing XLA_FLAGS
+        # value keeps its other flags
+        os.environ["JAX_PLATFORMS"] = "cpu"
         flag = f"--xla_force_host_platform_device_count={args.nodes}"
         if "host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
             os.environ["XLA_FLAGS"] = (
                 os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
 
+    import jax
     from repro import api
 
     mesh = None
@@ -122,7 +125,8 @@ def main():
                   if result.wire["ratio_vs_dense"] > 1 else "")
             tm = (f"  telemetry={result.telemetry['path']}"
                   if result.telemetry else "")
-            print(f"alpha={alpha:5.1f}  {method:12s}  "
+            print(f"platform={jax.devices()[0].platform}  "
+                  f"alpha={alpha:5.1f}  {method:12s}  "
                   f"test acc={result.final['acc']:.4f}  "
                   f"final loss={result.final['loss']:.3f}  "
                   f"consensus={result.final['consensus']:.2e}{bw}{tm}")

@@ -20,8 +20,11 @@ Runs on CPU in a couple of minutes:
 """
 import os
 
-# forced host devices MUST be set before jax initializes
+# a CPU study: forced host devices MUST be set before jax initializes
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+
+import jax                                                # noqa: E402
 
 from repro import api                                     # noqa: E402
 from repro.launch.mesh import make_debug_mesh             # noqa: E402
@@ -30,7 +33,8 @@ mesh = make_debug_mesh(shape=(8,), axes=("data",))
 
 spec = api.presets.get("n1024_churn").override("loop.steps=20",
                                                "loop.log_every=5")
-print(f"{spec.name}: n={spec.topology.n} on {spec.topology.name}, "
+print(f"platform={jax.devices()[0].platform}  "
+      f"{spec.name}: n={spec.topology.n} on {spec.topology.name}, "
       f"participation={spec.scenario.participation}, "
       f"dropout={spec.scenario.dropout} "
       f"(window={spec.scenario.churn_window}), "

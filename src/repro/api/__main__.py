@@ -20,6 +20,8 @@ import argparse
 import os
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import presets
 from .build import run
 from .spec import ExperimentSpec
@@ -47,6 +49,7 @@ def main(argv=None):
                          "--checkpoint PATH`)")
     ap.add_argument("--list", action="store_true", help="list presets")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.list or not args.spec:
         print("\n".join(presets.names()))

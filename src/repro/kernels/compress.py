@@ -17,14 +17,15 @@ both the compressed value and the residual in the same pass:
     ``comm/choco.mix_site`` packs the whole tree (``kernels/pack.py``) and
     calls it ONCE per mix site
 
-Grid layout follows qg_update.py: (rows, feature-tiles) over VMEM blocks of
-the flattened per-node message; per-row scalars (threshold / scale) ride in
-[rows, 1] blocks.  Feature-tile padding is bucketed to power-of-two tile
-multiples (``pack.bucket_size``) so heterogeneous message widths compile
-O(log n) variants.  Oracles: ``ref.threshold_mask_ref`` /
-``ref.quantize_dequantize_ref`` / ``ref.gamma_correct_ref``; parity is
-pinned in tests/test_comm.py and tests/test_kernels.py, including
-non-tile-multiple shapes.
+Grid layout: (row-blocks, feature-tiles) over VMEM blocks of the flattened
+per-node message.  A row block is 8 rows (the sublane count of a TPU vreg;
+fewer rows form one whole-array block), and per-row scalars (threshold /
+scale) ride as a [rows, 1] column in matching [8, 1] blocks.  Feature-tile
+padding is bucketed to power-of-two tile multiples (``pack.bucket_size``)
+so heterogeneous message widths compile O(log n) variants.  Oracles:
+``ref.threshold_mask_ref`` / ``ref.quantize_dequantize_ref`` /
+``ref.gamma_correct_ref``; parity is pinned in tests/test_comm.py and
+tests/test_kernels.py, including non-tile-multiple shapes.
 """
 from __future__ import annotations
 
@@ -36,15 +37,16 @@ from jax.experimental import pallas as pl
 
 from . import pack as _pack
 
-TILE = 16 * 1024  # fp32 lanes per block: 64 KiB/operand, 5 operands < 1 MiB
+TILE = 16 * 1024  # fp32 lanes per block row: 512 KiB per [8, TILE] operand
 _FLOOR = 128
+_ROWS = 8         # sublanes per vreg: the row-block height the TPU requires
 
 _TINY = 1e-12
 
 
 def _threshold_mask_kernel(x_ref, thr_ref, q_ref, r_ref):
     x = x_ref[...]
-    thr = thr_ref[0, 0]
+    thr = thr_ref[...]                    # [rows, 1], broadcast per row
     q = jnp.where(jnp.abs(x) >= thr, x, 0.0)
     q_ref[...] = q
     r_ref[...] = x - q
@@ -52,7 +54,7 @@ def _threshold_mask_kernel(x_ref, thr_ref, q_ref, r_ref):
 
 def _qdq_kernel(x_ref, s_ref, u_ref, q_ref, r_ref, *, levels):
     x = x_ref[...]
-    s = jnp.maximum(s_ref[0, 0], _TINY)
+    s = jnp.maximum(s_ref[...], _TINY)
     y = jnp.abs(x) * (levels / s)
     xi = jnp.minimum(jnp.floor(y + u_ref[...]), levels)
     q = jnp.sign(x) * xi * (s / levels)
@@ -61,20 +63,22 @@ def _qdq_kernel(x_ref, s_ref, u_ref, q_ref, r_ref, *, levels):
 
 
 def _rowwise_call(kernel, x2d, row_scalars, extras, *, interpret):
-    """Launch over (rows, feature-tiles); ``row_scalars`` are [rows] values
-    broadcast per row, ``extras`` are [rows, f] element-wise operands."""
+    """Launch over (row-blocks, feature-tiles); ``row_scalars`` are [rows]
+    values broadcast per row, ``extras`` are [rows, f] element-wise
+    operands."""
     rows, f = x2d.shape
     padded_f = _pack.bucket_size(f, tile=TILE, floor=_FLOOR)
     tile = min(TILE, padded_f)
-    pad = padded_f - f
+    rb = rows if rows <= _ROWS else _ROWS
+    pad_r = -rows % rb
     full = [x2d.astype(jnp.float32)] + [e.astype(jnp.float32) for e in extras]
-    if pad:
-        full = [jnp.pad(a, ((0, 0), (0, pad))) for a in full]
-    scal = [s.reshape(rows, 1).astype(jnp.float32) for s in row_scalars]
+    full = [jnp.pad(a, ((0, pad_r), (0, padded_f - f))) for a in full]
+    scal = [jnp.pad(s.reshape(rows, 1).astype(jnp.float32),
+                    ((0, pad_r), (0, 0))) for s in row_scalars]
 
-    grid = (rows, full[0].shape[1] // tile)
-    full_spec = pl.BlockSpec((1, tile), lambda i, j: (i, j))
-    scal_spec = pl.BlockSpec((1, 1), lambda i, j: (i, 0))
+    grid = ((rows + pad_r) // rb, padded_f // tile)
+    full_spec = pl.BlockSpec((rb, tile), lambda i, j: (i, j))
+    scal_spec = pl.BlockSpec((rb, 1), lambda i, j: (i, 0))
     out_shape = jax.ShapeDtypeStruct(full[0].shape, jnp.float32)
     # operand order: x, row-scalars, element-wise extras
     q, r = pl.pallas_call(
@@ -86,7 +90,7 @@ def _rowwise_call(kernel, x2d, row_scalars, extras, *, interpret):
         out_shape=(out_shape, out_shape),
         interpret=interpret,
     )(full[0], *scal, *full[1:])
-    return q[:, :f], r[:, :f]
+    return q[:rows, :f], r[:rows, :f]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -163,8 +163,8 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(live)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * scale     # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)          # [ps, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)             # [ps, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [G, ps]
         if softcap:
@@ -196,18 +196,19 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            interpret: bool = True):
     """Single-token decode over a paged KV pool (DESIGN.md §13).
 
-    q [B,1,H,D]; k/v_pages [NP,ps,K,D]; block_tables [B,P] int32 page ids
+    q [B,1,H,D]; k/v_pages [NP,K,ps,D]; block_tables [B,P] int32 page ids
     (-1 = unallocated); lengths [B] int32 tokens written per slot (incl. the
     current one).  Returns [B,1,H,D].
 
     The block table and lengths ride in as scalar prefetch: the k/v
     BlockSpec index maps read ``bt[b, j]`` to DMA exactly the slot's own
     pages — no [B, T] gather materialization, bytes moved per step are
-    O(lengths), not O(pool).
+    O(lengths), not O(pool).  Each page keeps its (ps, D) tile last, the
+    block shape a TPU DMA can move per (page, KV head).
     """
     b, one, h, d = q.shape
     assert one == 1
-    n_p, ps, kh, _ = k_pages.shape
+    n_p, kh, ps, _ = k_pages.shape
     assert h % kh == 0
     g = h // kh
     p_max = block_tables.shape[1]
@@ -221,12 +222,12 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
         grid=(b, kh, p_max),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda bb, hh, j, bt, ln: (bb, hh, 0, 0)),
-            pl.BlockSpec((1, ps, 1, d),
+            pl.BlockSpec((1, 1, ps, d),
                          lambda bb, hh, j, bt, ln:
-                         (jnp.maximum(bt[bb, j], 0), 0, hh, 0)),
-            pl.BlockSpec((1, ps, 1, d),
+                         (jnp.maximum(bt[bb, j], 0), hh, 0, 0)),
+            pl.BlockSpec((1, 1, ps, d),
                          lambda bb, hh, j, bt, ln:
-                         (jnp.maximum(bt[bb, j], 0), 0, hh, 0)),
+                         (jnp.maximum(bt[bb, j], 0), hh, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
                                lambda bb, hh, j, bt, ln: (bb, hh, 0, 0)),

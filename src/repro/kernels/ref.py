@@ -115,20 +115,19 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
 def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
                                window: int = 0,
                                softcap: float = 0.0) -> jax.Array:
-    """Dense-gather oracle.  q [B,1,H,D]; k/v_pages [NP,ps,K,D];
+    """Dense-gather oracle.  q [B,1,H,D]; k/v_pages [NP,K,ps,D];
     block_tables [B,P] (-1 = unallocated); lengths [B] tokens written per
     slot (incl. the current one).  Gathers each slot's pages into a dense
     [B, P*ps, K, D] cache and runs a masked softmax."""
     b, _, h, d = q.shape
-    n_p, ps, kh, _ = k_pages.shape
+    n_p, kh, ps, _ = k_pages.shape
     g = h // kh
     p_max = block_tables.shape[1]
     t = p_max * ps
     t_idx = jnp.arange(t)
-    rows = jnp.clip(block_tables[:, t_idx // ps] * ps + t_idx % ps,
-                    0, n_p * ps - 1)                      # [B, T]
-    ks = jnp.take(k_pages.reshape(n_p * ps, kh, d), rows, axis=0)
-    vs = jnp.take(v_pages.reshape(n_p * ps, kh, d), rows, axis=0)
+    page = jnp.clip(block_tables[:, t_idx // ps], 0, n_p - 1)   # [B, T]
+    ks = k_pages[page, :, t_idx % ps]                     # [B, T, K, D]
+    vs = v_pages[page, :, t_idx % ps]
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
     qf = q.reshape(b, 1, kh, g, d).astype(jnp.float32) * scale
     sc = jnp.einsum("bskgd,btkd->bskgt", qf, ks.astype(jnp.float32))

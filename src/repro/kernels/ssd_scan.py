@@ -16,6 +16,13 @@ Inputs are pre-arranged by ``ops.ssd_scan``:
   c   [BH, S, N]   output projections
 Outputs: y [BH, S, P], final_state [BH, N, P].
 (The D-skip term is applied outside the kernel.)
+
+The wrapper splits S into [n_chunks, L] so that every block's last two dims
+are whole array dims (the TPU's block-shape rule), and hands the per-step
+vectors over as both a column [L, 1] and a row [1, L]: the kernel needs
+them along both axes of the [L, L] intra-chunk matrix, and a vreg relayout
+is not something Mosaic does for free.  The chunk-local cumulative decay
+is a cheap [BH, S] XLA cumsum outside the kernel.
 """
 from __future__ import annotations
 
@@ -27,46 +34,50 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, adt_ref, b_ref, c_ref, y_ref, fin_ref,
-                state_ref, *, chunk, n_chunks):
+def _ssd_kernel(x_ref, dtc_ref, dtr_ref, cumc_ref, cumr_ref, tot_ref, b_ref,
+                c_ref, y_ref, fin_ref, state_ref, *, chunk, n_chunks):
     k = pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0].astype(jnp.float32)      # [L, P]
-    dt = dt_ref[0].astype(jnp.float32)    # [L]
-    adt = adt_ref[0].astype(jnp.float32)  # [L]
-    b = b_ref[0].astype(jnp.float32)      # [L, N]
-    c = c_ref[0].astype(jnp.float32)      # [L, N]
+    x = x_ref[0, 0].astype(jnp.float32)   # [L, P]
+    dt_c = dtc_ref[0, 0]                  # [L, 1]  dt_s down the column
+    dt_r = dtr_ref[0, 0]                  # [1, L]  dt_s along the row
+    cum_c = cumc_ref[0, 0]                # [L, 1]  s_t within chunk
+    cum_r = cumr_ref[0, 0]                # [1, L]
+    b = b_ref[0, 0].astype(jnp.float32)   # [L, N]
+    c = c_ref[0, 0].astype(jnp.float32)   # [L, N]
 
-    cum = jnp.cumsum(adt)                 # s_t within chunk  [L]
     # intra-chunk: M[t,s] = (C_t . B_s) * exp(s_t - s_s) * dt_s   (causal)
     gram = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)  # [L, L]
-    dec = cum[:, None] - cum[None, :]
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     causal = t_idx >= s_idx
-    m = jnp.where(causal, gram * jnp.exp(dec) * dt[None, :], 0.0)
+    m = jnp.where(causal, gram * jnp.exp(cum_c - cum_r) * dt_r, 0.0)
     y = jax.lax.dot_general(m, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [L, P]
 
     # inter-chunk: y += (C exp(s_t)) @ state
     state = state_ref[...]                # [N, P]
-    w_in = jnp.exp(cum)[:, None]          # [L, 1]
-    y = y + jax.lax.dot_general(c * w_in, state, (((1,), (0,)), ((), ())),
+    y = y + jax.lax.dot_general(c * jnp.exp(cum_c), state,
+                                (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
 
-    # state update: state' = exp(total) * state + sum_s exp(total - s_s) dt_s B_s x_s
-    total = cum[chunk - 1]
-    w_out = (jnp.exp(total - cum) * dt)[:, None]  # [L, 1]
-    state_new = jnp.exp(total) * state + jax.lax.dot_general(
+    # state update:
+    #   state' = exp(total) * state + sum_s exp(total - s_s) dt_s B_s x_s
+    # the chunk's total decay s_L is an SMEM scalar: a [1, 1] vector slice
+    # cannot be broadcast to [N, P] (sublanes and lanes at once)
+    total = tot_ref[0, 0, 0, 0]
+    w_out = jnp.exp(total - cum_c) * dt_c  # [L, 1]
+    decay = jnp.exp(jnp.full((1, state.shape[1]), total))
+    state_new = decay * state + jax.lax.dot_general(
         b * w_out, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)       # [N, P]
     state_ref[...] = state_new
-    y_ref[0, ...] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     @pl.when(k == n_chunks - 1)
     def _emit_state():
@@ -83,26 +94,35 @@ def ssd_scan_bh(x, dt, adt, b, c, *, chunk: int = 128,
     assert s % chunk == 0, (s, chunk)
     n_chunks = s // chunk
 
+    dt = dt.astype(jnp.float32).reshape(bh, n_chunks, chunk)
+    cum = jnp.cumsum(adt.astype(jnp.float32).reshape(bh, n_chunks, chunk),
+                     axis=-1)
+    col = lambda v: v[..., None]          # [BH, nC, L, 1]
+    row = lambda v: v[..., None, :]       # [BH, nC, 1, L]
+    split = lambda v: v.reshape(bh, n_chunks, chunk, v.shape[-1])
+
+    def blk(last):
+        return pl.BlockSpec((1, 1) + last, lambda i, k: (i, k, 0, 0))
+
     kernel = functools.partial(_ssd_kernel, chunk=chunk, n_chunks=n_chunks)
     y, fin = pl.pallas_call(
         kernel,
         grid=(bh, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, chunk, p), lambda i, k: (i, k, 0)),
-            pl.BlockSpec((1, chunk), lambda i, k: (i, k)),
-            pl.BlockSpec((1, chunk), lambda i, k: (i, k)),
-            pl.BlockSpec((1, chunk, n), lambda i, k: (i, k, 0)),
-            pl.BlockSpec((1, chunk, n), lambda i, k: (i, k, 0)),
-        ],
+        in_specs=[blk((chunk, p)), blk((chunk, 1)), blk((1, chunk)),
+                  blk((chunk, 1)), blk((1, chunk)),
+                  pl.BlockSpec((1, 1, 1, 1), lambda i, k: (i, k, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  blk((chunk, n)), blk((chunk, n))],
         out_specs=[
-            pl.BlockSpec((1, chunk, p), lambda i, k: (i, k, 0)),
+            blk((chunk, p)),
             pl.BlockSpec((1, n, p), lambda i, k: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, p), x.dtype),
+            jax.ShapeDtypeStruct((bh, n_chunks, chunk, p), x.dtype),
             jax.ShapeDtypeStruct((bh, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(x, dt, adt, b, c)
-    return y, fin
+    )(split(x), col(dt), row(dt), col(cum), row(cum), col(col(cum[..., -1])),
+      split(b), split(c))
+    return y.reshape(bh, s, p), fin

@@ -1,4 +1,6 @@
 import os
+# a CPU study by design: 512 placeholder host devices, never the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh)
@@ -240,6 +242,7 @@ def run_combo(arch: str, shape_name: str, mesh_name: str, *,
         record.update({k: v for k, v in summary.items()
                        if k not in ("arch", "shape", "memory_analysis")})
 
+    record["platform"] = jax.devices()[0].platform
     os.makedirs(out_dir, exist_ok=True)
     with open(out_path, "w") as f:
         json.dump(record, f, indent=1, default=str)

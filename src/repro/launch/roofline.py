@@ -110,13 +110,9 @@ def parse_collectives(hlo_text: str) -> dict:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` across the jax API drift: older releases
-    return a single dict, 0.4.x returns a one-element list of per-device
-    dicts, and either may be empty/None."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()``, or ``{}`` where the backend gives
+    none."""
+    return compiled.cost_analysis() or {}
 
 
 @dataclasses.dataclass

@@ -42,6 +42,7 @@ from repro import api
 from repro.api import presets
 from repro.api.models import resolve_transformer_config
 from repro.core import topology as topo_lib
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_spec(args) -> api.ExperimentSpec:
@@ -100,6 +101,7 @@ def main(argv=None):
     ap.add_argument("--set", dest="overrides", action="append", default=[],
                     metavar="KEY=VALUE", help="dotted spec override")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     spec = presets.get(args.preset) if args.preset else build_spec(args)
     if args.overrides:

@@ -200,29 +200,30 @@ def _paged_self_attn(p, x, window: int, ctx: RunCtx, cache):
     """Paged-KV attention for one layer: scatter the chunk's K/V into the
     layer's page pool, then attend over the slot's gathered pages (or the
     gather-free Pallas kernel for single-token decode).  ``cache`` is
-    ``{"k": [NP, ps, KH, D], "v": ...}`` — the pool, NOT a per-slot
-    buffer."""
+    ``{"k": [NP, KH, ps, D], "v": ...}`` — the pool, NOT a per-slot
+    buffer.  A flat pool row ``r`` is page ``r // ps``, offset ``r % ps``."""
     cfg, pg = ctx.cfg, ctx.pages
     hd = cfg.resolved_head_dim
     b, c, _ = x.shape
     q, k, v = attention.qkv(p, x, cfg.n_heads, cfg.n_kv_heads, hd)
     q = layers.apply_rope(q, pg.q_pos, cfg.rope_theta)
     k = layers.apply_rope(k, pg.q_pos, cfg.rope_theta)
-    n_pages, ps, kh, _ = cache["k"].shape
-    kf = cache["k"].reshape(n_pages * ps, kh, hd)
-    vf = cache["v"].reshape(n_pages * ps, kh, hd)
-    kf = kf.at[pg.scatter_idx].set(k.reshape(b * c, kh, hd), mode="drop")
-    vf = vf.at[pg.scatter_idx].set(v.reshape(b * c, kh, hd), mode="drop")
-    new_cache = {"k": kf.reshape(n_pages, ps, kh, hd),
-                 "v": vf.reshape(n_pages, ps, kh, hd)}
+    kh, ps = cache["k"].shape[1], cache["k"].shape[2]
+    sp, so = pg.scatter_idx // ps, pg.scatter_idx % ps
+    new_cache = {
+        "k": cache["k"].at[sp, :, so].set(k.reshape(b * c, kh, hd),
+                                          mode="drop"),
+        "v": cache["v"].at[sp, :, so].set(v.reshape(b * c, kh, hd),
+                                          mode="drop")}
     if pg.use_pallas and c == 1:
         from repro.kernels import ops as kops
         out = kops.paged_decode_attention(
             q, new_cache["k"], new_cache["v"], pg.block_tables, pg.lengths,
             window=window, softcap=cfg.attn_softcap)
     else:
-        ks = jnp.take(kf, pg.gather_idx, axis=0)   # [B, T, KH, D]
-        vs = jnp.take(vf, pg.gather_idx, axis=0)
+        gp, go = pg.gather_idx // ps, pg.gather_idx % ps
+        ks = new_cache["k"][gp, :, go]             # [B, T, KH, D]
+        vs = new_cache["v"][gp, :, go]
         out = attention.paged_attention(q, ks, vs, pg.q_pos, window=window,
                                         softcap=cfg.attn_softcap)
     out = out.reshape(b, c, cfg.n_heads * hd)
@@ -573,9 +574,9 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
     hd = cfg.resolved_head_dim
 
     def one():
-        return {"k": jnp.zeros((n_pages, page_size, cfg.n_kv_heads, hd),
+        return {"k": jnp.zeros((n_pages, cfg.n_kv_heads, page_size, hd),
                                dtype),
-                "v": jnp.zeros((n_pages, page_size, cfg.n_kv_heads, hd),
+                "v": jnp.zeros((n_pages, cfg.n_kv_heads, page_size, hd),
                                dtype)}
 
     def stacked():

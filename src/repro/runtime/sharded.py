@@ -168,6 +168,24 @@ class ShardedRuntime(Runtime):
             return self._global_put(batch, lead=lead)
         return jax.tree.map(jnp.asarray, batch)
 
+    def _put_replicated(self, x):
+        """Commit ``x`` replicated over the mesh.  The chunk returns its rng
+        carry with this sharding, so a host-made key must enter with it too:
+        otherwise the second chunk sees a new input type and retraces (and
+        recompiles) the whole step."""
+        sh = NamedSharding(self.mesh, P())
+        if getattr(x, "sharding", None) == sh:
+            return x
+        if jax.process_count() > 1:
+            a = np.asarray(x)
+            return jax.make_array_from_callback(a.shape, sh,
+                                                lambda idx: a[idx])
+        return jax.device_put(x, sh)
+
+    def step_chunk(self, state, batches, rng, collect: bool = False):
+        return super().step_chunk(state, batches, self._put_replicated(rng),
+                                  collect=collect)
+
     # -- compilation: ONE shard_map per step / per chunk ----------------------
     def _shard(self, fn, in_specs, out_specs):
         return gossip._shard_map(

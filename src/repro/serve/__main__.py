@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 
 from .engine import Request, ServeEngine, sequential_generate
@@ -67,6 +68,7 @@ def main(argv=None):
                          "engine")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.checkpoint:
         params, cfg = load_serving_checkpoint(args.checkpoint)

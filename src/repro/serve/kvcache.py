@@ -1,7 +1,7 @@
 """Paged KV-cache: fixed-size pages + per-slot block tables (DESIGN.md §13).
 
 The device side is one K/V page pool per attention layer
-(``transformer.init_paged_cache``): ``[n_pages, page_size, KH, D]`` with NO
+(``transformer.init_paged_cache``): ``[n_pages, KH, page_size, D]`` with NO
 batch axis.  This host-side manager owns the *placement*: a block table
 ``[n_slots, p_max]`` mapping each slot's logical page index to a pool page
 (-1 = unallocated), a free list, and reservation accounting.

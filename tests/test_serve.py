@@ -241,8 +241,8 @@ def test_paged_kernel_matches_ref(b, h, kh, d, ps, pmax, np_, window,
                                   softcap):
     ks = jax.random.split(jax.random.PRNGKey(b * 100 + ps), 4)
     q = jax.random.normal(ks[0], (b, 1, h, d))
-    k_pages = jax.random.normal(ks[1], (np_, ps, kh, d))
-    v_pages = jax.random.normal(ks[2], (np_, ps, kh, d))
+    k_pages = jax.random.normal(ks[1], (np_, kh, ps, d))
+    v_pages = jax.random.normal(ks[2], (np_, kh, ps, d))
     lengths = jax.random.randint(ks[3], (b,), 1,
                                  min(pmax, np_) * ps + 1)
     bt = np.full((b, pmax), -1, np.int32)
