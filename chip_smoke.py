@@ -52,34 +52,6 @@ MAMBA_STEPS = 3
 SERVE_REQUESTS, SERVE_NEW = 8, 16
 
 
-class CompileLog:
-    """Per-phase compile seconds and persistent-cache hits, from JAX's own
-    monitoring events."""
-
-    def __init__(self):
-        import jax
-        self.reset()
-        jax.monitoring.register_event_listener(self._event)
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-
-    def reset(self):
-        self.secs, self.requests, self.hits = 0.0, 0, 0
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def _duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.secs += secs
-
-    def summary(self) -> str:
-        return (f"compile_s={self.secs:.1f} "
-                f"cache_hits={self.hits}/{self.requests}")
-
-
 def _report(name, log, t0, **fields):
     body = " ".join(f"{k}={v}" for k, v in fields.items())
     print(f"[{name}] {body} {log.summary()} wall_s={time.time() - t0:.1f}",
@@ -332,7 +304,8 @@ def main(argv=None) -> int:
     cache_dir = enable_compile_cache()
     print(f"[setup] device={dev.device_kind} count={len(devices)} "
           f"jax={jax.__version__} cache_dir={cache_dir}", flush=True)
-    log = CompileLog()
+    from repro.telemetry import trace
+    log = trace.enable().compiles
     if args.chips == 4:
         phase_sharded(log)
     else:

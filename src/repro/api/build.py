@@ -20,6 +20,7 @@ import numpy as np
 from repro.comm import count_mix_sites, make_comm
 from repro.core import topology as topo_lib
 from repro.core.optim import ChainOptimizer, make_optimizer
+from repro.telemetry.trace import host_span
 from repro.train import (DecentralizedTrainer, TrainState, lr_schedule,
                          run_training, run_training_scanned)
 
@@ -86,7 +87,8 @@ def build(spec: ExperimentSpec, *, mesh: Any = None) -> Experiment:
     spec) activates the sharded gossip schedules per ``spec.gossip``."""
     spec.validate()
     topo = topo_lib.get_topology(spec.topology.name, spec.topology.n)
-    task = build_task(spec, topo.n)
+    with host_span("tm/setup/data"):    # synthesis + Dirichlet partition
+        task = build_task(spec, topo.n)
     bundle = MODELS[spec.model.name](spec, task)
 
     lp = spec.loop

@@ -62,7 +62,7 @@ def _qdq_kernel(x_ref, s_ref, u_ref, q_ref, r_ref, *, levels):
     r_ref[...] = x - q
 
 
-def _rowwise_call(kernel, x2d, row_scalars, extras, *, interpret):
+def _rowwise_call(kernel, x2d, row_scalars, extras, *, name, interpret):
     """Launch over (row-blocks, feature-tiles); ``row_scalars`` are [rows]
     values broadcast per row, ``extras`` are [rows, f] element-wise
     operands."""
@@ -89,6 +89,7 @@ def _rowwise_call(kernel, x2d, row_scalars, extras, *, interpret):
         out_specs=(full_spec, full_spec),
         out_shape=(out_shape, out_shape),
         interpret=interpret,
+        name=name,
     )(full[0], *scal, *full[1:])
     return q[:rows, :f], r[:rows, :f]
 
@@ -98,7 +99,7 @@ def threshold_mask(x2d, thr, *, interpret: bool = True):
     """Fused magnitude-threshold sparsification.  x2d [rows, f]; thr [rows]
     (k-th largest |x| per row).  Returns (kept, residual), fp32."""
     return _rowwise_call(_threshold_mask_kernel, x2d, [thr], [],
-                         interpret=interpret)
+                         name="threshold_mask", interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("levels", "interpret"))
@@ -108,7 +109,8 @@ def quantize_dequantize(x2d, scale, u, *, levels: int,
     scale [rows] (max |x| per row); u [rows, f] uniform in [0, 1).
     Returns (dequantized, residual), fp32."""
     kernel = functools.partial(_qdq_kernel, levels=levels)
-    return _rowwise_call(kernel, x2d, [scale], [u], interpret=interpret)
+    return _rowwise_call(kernel, x2d, [scale], [u],
+                         name="quantize_dequantize", interpret=interpret)
 
 
 def _gamma_correct_kernel(x_ref, mx_ref, h_ref, o_ref, *, gamma):
@@ -123,5 +125,5 @@ def gamma_correct(x, mixed, anchor, *, gamma: float, interpret: bool = True):
     the whole tree once.  ``gamma`` is the resolved consensus step size —
     a static, it never changes within a run."""
     kernel = functools.partial(_gamma_correct_kernel, gamma=gamma)
-    return _pack.flat_call(kernel, (x, mixed, anchor), tile=TILE,
-                           floor=_FLOOR, interpret=interpret)
+    return _pack.flat_call(kernel, (x, mixed, anchor), name="gamma_correct",
+                           tile=TILE, floor=_FLOOR, interpret=interpret)

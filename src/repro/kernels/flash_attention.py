@@ -130,6 +130,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qh, kh_arr, vh_arr)
     out = out[:, :s, :].reshape(b, h, s, d)
     return jnp.moveaxis(out, 1, 2)
@@ -241,5 +242,6 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables, lengths, qr, k_pages, v_pages)
     return out.reshape(b, 1, h, d)

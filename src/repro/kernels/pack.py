@@ -173,16 +173,17 @@ def unpack(spec: PackSpec, buf: jax.Array) -> PyTree:
 # shared 1D elementwise launcher
 # ---------------------------------------------------------------------------
 
-def flat_call(kernel, args, *, n_out: int = 1, scalars=(), tile: int,
-              floor: int, interpret: bool, bucket: bool = True):
+def flat_call(kernel, args, *, name: str, n_out: int = 1, scalars=(),
+              tile: int, floor: int, interpret: bool, bucket: bool = True):
     """Launch an elementwise kernel over 1D tiles of the flattened ``args``.
 
     ``scalars`` are traced per-launch values (lr, refresh gates) shipped as
     [1] fp32 operands with a broadcast BlockSpec — they cannot be statics
     because the jitted step traces them.  ``bucket=True`` pads to
     :func:`bucket_size`; ``bucket=False`` assumes the caller already padded
-    to a tile multiple (the packed whole-tree path).  Returns a tuple of
-    ``n_out`` outputs shaped like ``args[0]``.
+    to a tile multiple (the packed whole-tree path).  ``name`` is the
+    kernel's name in compiled programs and device traces.  Returns a tuple
+    of ``n_out`` outputs shaped like ``args[0]``.
     """
     flat = [a.reshape(-1) for a in args]
     n = flat[0].size
@@ -205,6 +206,7 @@ def flat_call(kernel, args, *, n_out: int = 1, scalars=(), tile: int,
         out_specs=tuple(spec for _ in range(n_out)),
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
     )(*flat, *[jnp.asarray(s, jnp.float32).reshape(1) for s in scalars])
     outs = tuple(o[:n].reshape(args[0].shape) for o in outs)
     return outs if n_out > 1 else outs[0]

@@ -54,13 +54,13 @@ def _buffer_update_kernel(xo_ref, xn_ref, m_ref, o_ref, *, inv_eta, mu):
     o_ref[...] = mu * m + (1.0 - mu) * (xo - xn) * inv_eta
 
 
-def _flat_call(kernel, args, *, interpret: bool, n_out: int = 1,
+def _flat_call(kernel, args, *, name: str, interpret: bool, n_out: int = 1,
                scalars=(), bucket: bool = True):
     """Launch an elementwise kernel over 1D tiles of flattened input
     (bucketed padding — see ``pack.bucket_size``)."""
-    return _pack.flat_call(kernel, args, n_out=n_out, scalars=scalars,
-                           tile=TILE, floor=_FLOOR, interpret=interpret,
-                           bucket=bucket)
+    return _pack.flat_call(kernel, args, name=name, n_out=n_out,
+                           scalars=scalars, tile=TILE, floor=_FLOOR,
+                           interpret=interpret, bucket=bucket)
 
 
 @functools.partial(jax.jit, static_argnames=("eta", "beta", "nesterov",
@@ -69,14 +69,16 @@ def qg_local_step(x, m_hat, g, *, eta: float, beta: float,
                   nesterov: bool = False, interpret: bool = True):
     kernel = functools.partial(_local_step_kernel, eta=eta, beta=beta,
                                nesterov=nesterov)
-    return _flat_call(kernel, (x, m_hat, g), interpret=interpret)
+    return _flat_call(kernel, (x, m_hat, g), name="qg_local_step",
+                      interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("eta", "mu", "interpret"))
 def qg_buffer_update(x_old, x_new, m_hat, *, eta: float, mu: float,
                      interpret: bool = True):
     kernel = functools.partial(_buffer_update_kernel, inv_eta=1.0 / eta, mu=mu)
-    return _flat_call(kernel, (x_old, x_new, m_hat), interpret=interpret)
+    return _flat_call(kernel, (x_old, x_new, m_hat), name="qg_buffer_update",
+                      interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -125,8 +127,9 @@ def fused_halfstep(x, m, g, eta, *, beta: float, wd: float = 0.0,
     """
     kernel = functools.partial(_fused_halfstep_kernel, beta=beta, wd=wd,
                                nesterov=nesterov)
-    return _flat_call(kernel, (x, m, g), n_out=2 if emit_m else 1,
-                      scalars=(eta,), interpret=interpret)
+    return _flat_call(kernel, (x, m, g), name="fused_halfstep",
+                      n_out=2 if emit_m else 1, scalars=(eta,),
+                      interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("mu", "interpret"))
@@ -137,5 +140,5 @@ def fused_qg_buffer(x_pre, x_post, m_hat, eta, refresh, *, mu: float,
     ``refresh`` (traced bool/int scalar) gates the write — off-cadence tau
     steps carry the old buffer through unchanged."""
     kernel = functools.partial(_fused_qg_buffer_kernel, mu=mu)
-    return _flat_call(kernel, (x_pre, x_post, m_hat), n_out=1,
-                      scalars=(eta, refresh), interpret=interpret)
+    return _flat_call(kernel, (x_pre, x_post, m_hat), name="fused_qg_buffer",
+                      n_out=1, scalars=(eta, refresh), interpret=interpret)

@@ -123,6 +123,7 @@ def ssd_scan_bh(x, dt, adt, b, c, *, chunk: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(split(x), col(dt), row(dt), col(cum), row(cum), col(col(cum[..., -1])),
       split(b), split(c))
     return y.reshape(bh, s, p), fin

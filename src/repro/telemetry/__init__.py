@@ -9,8 +9,10 @@ Three layers, composable or separable:
     pick a separately compiled collecting trace per step/chunk), so the
     telemetry-off — and off-cadence — path runs the exact telemetry-less
     graph;
-  * **spans + timing** (:mod:`repro.telemetry.trace`) — always-on HLO/host
-    region labels and a host-side ring-buffer step timer;
+  * **spans + timing** (:mod:`repro.telemetry.trace`) — host spans on the
+    profiler's timeline (``host_span``, ``step_span``), an in-process
+    registry of span seconds and a compile counter (``CompileLog``), both
+    off until ``trace.enable()``, and a host-side ring-buffer step timer;
   * **sinks + recorder** (:mod:`repro.telemetry.sinks`, ``.recorder``) —
     the host side: split ``tm.`` keys off the step metrics, stream rows to
     memory/JSONL/CSV, summarize.
@@ -26,11 +28,12 @@ from repro.telemetry.recorder import TelemetryRecorder
 from repro.telemetry.sinks import (
     SINKS, CsvSink, JsonlSink, MemorySink, TelemetrySink, make_sink,
     read_csv, read_jsonl)
-from repro.telemetry.trace import StepTimer, graph_span, span
+from repro.telemetry.trace import CompileLog, StepTimer, host_span, step_span
 
 __all__ = [
     "METRICS", "DEFAULT_METRICS", "TM_PREFIX", "CollectorCtx", "MetricsSpec",
     "TelemetryConfig", "resolve_config", "TelemetryRecorder", "SINKS",
     "CsvSink", "JsonlSink", "MemorySink", "TelemetrySink", "make_sink",
-    "read_csv", "read_jsonl", "StepTimer", "graph_span", "span",
+    "read_csv", "read_jsonl", "StepTimer", "CompileLog", "host_span",
+    "step_span",
 ]

@@ -17,6 +17,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 import chip_smoke  # noqa: E402
+from repro.telemetry import trace  # noqa: E402
 
 TINY_RESNET = dict(steps=2, hw=8, batch=4)
 TINY_MAMBA = dict(batch=1, seq=128, steps=2, data_vocab=256, reduced=True)
@@ -27,7 +28,8 @@ def cpu_rehearsal(monkeypatch):
     from repro.core import transforms
     monkeypatch.setattr(transforms, "_fused_enabled", lambda f: f != "off")
     monkeypatch.setattr(chip_smoke, "KERNEL_MARKER", "func.func")
-    return chip_smoke.CompileLog()
+    yield trace.enable().compiles
+    trace.disable()
 
 
 def test_refuses_without_a_tpu(capsys):
@@ -53,8 +55,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 sys.path.insert(0, ".")
 import chip_smoke
 from repro.core import transforms
+from repro.telemetry import trace
 transforms._fused_enabled = lambda f: f != "off"
-chip_smoke.phase_sharded(chip_smoke.CompileLog(), resnet=%r, mamba=%r)
+chip_smoke.phase_sharded(trace.enable().compiles, resnet=%r, mamba=%r)
 print("SHARDED_OK")
 """ % (TINY_RESNET, TINY_MAMBA)
 

@@ -14,6 +14,7 @@ TPU executable written here could not be read back without a chip).
 from __future__ import annotations
 
 import os
+import re
 
 import pytest
 
@@ -104,8 +105,18 @@ KERNELS = ["fused_halfstep", "fused_qg_buffer", "gamma_correct",
            "flash_attention_fp32", "paged_decode_attention", "ssd_scan_bh"]
 
 
+# the name each kernel passes to ``pallas_call``: the last scope of its
+# op's ``op_name`` in compiled programs (``tf_op`` in device traces)
+KERNEL_NAME = {"flash_attention_bf16": "flash_attention",
+               "flash_attention_fp32": "flash_attention",
+               "ssd_scan_bh": "ssd_scan"}
+
+
 @pytest.mark.parametrize("name", KERNELS)
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _cases(one_chip)[name]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), name
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, name
+    kernel = KERNEL_NAME.get(name, name)    # the op's scope in the trace
+    assert re.search(rf'op_name="[^"]*/{kernel}/pallas_call"', text), kernel
