@@ -120,7 +120,8 @@ def build(spec: ExperimentSpec, *, mesh: Any = None) -> Experiment:
                        backend=spec.comm.backend),
         mesh=mesh, node_axis=spec.gossip.node_axis,
         gossip_schedule=spec.gossip.schedule, runtime=spec.runtime,
-        overlap=spec.overlap, scenario=scenario, telemetry=telemetry_cfg)
+        overlap=spec.overlap, scenario=scenario, telemetry=telemetry_cfg,
+        loss_nodes_fn=bundle.loss_nodes_fn)
     state = trainer.init(jax.random.PRNGKey(spec.seed), bundle.init_fn)
     if telemetry_cfg is not None:
         # build-time constants for the 'wire'/'mixing' collectors — resolved

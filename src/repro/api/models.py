@@ -12,6 +12,10 @@ trainer needs:
 * ``eval_fn(params_i, mstate_i, batch) -> {metric_sums..., 'count'}`` or
   ``None`` when the experiment has no eval protocol (LM presets).
 
+and may carry a fourth, ``loss_nodes_fn``: ``loss_fn`` over a block of nodes
+at once, for models with a node-batched path that pays on this platform
+(ResNet-20 with EvoNorm or GroupNorm on a TPU).
+
 Register your own with ``@register_model("myname")`` and reference it from a
 spec as ``ModelSpec(name="myname", kwargs={...})`` — that is the whole
 "examples shrink to spec + a model plugin" contract.
@@ -34,6 +38,10 @@ class ModelBundle:
     init_fn: Callable
     loss_fn: Callable
     eval_fn: Optional[Callable] = None
+    # the same loss over a block of nodes at once (node-stacked params,
+    # model state, batch and rngs -> ``[b]`` losses); the runtimes take it
+    # for every local block where the model offers it (DESIGN.md §15)
+    loss_nodes_fn: Optional[Callable] = None
 
 
 MODELS: dict[str, Callable[..., ModelBundle]] = {}
@@ -139,6 +147,13 @@ def _resnet20(spec, task) -> ModelBundle:
         logits, ns = resnet.apply_resnet20(p, s, xb, norm=norm, train=True)
         return _ce(logits, yb), (ns, {})
 
+    loss_nodes_fn = None
+    if resnet.node_batched_serves(norm, spec.data.hw):
+        def loss_nodes_fn(p, s, batch, _rngs):
+            xb, yb = batch
+            logits = resnet.apply_resnet20_nodes(p, xb, norm=norm)
+            return jax.vmap(_ce)(logits, yb), (s, {})
+
     def eval_fn(p, s, batch):
         xb, yb = batch
         logits, _ = resnet.apply_resnet20(p, s, xb, norm=norm, train=False)
@@ -146,7 +161,7 @@ def _resnet20(spec, task) -> ModelBundle:
         return {"acc": jnp.sum(pred == yb.astype(jnp.int32)),
                 "count": jnp.asarray(len(yb), jnp.float32)}
 
-    return ModelBundle(init_fn, loss_fn, eval_fn)
+    return ModelBundle(init_fn, loss_fn, eval_fn, loss_nodes_fn)
 
 
 # ---------------------------------------------------------------------------

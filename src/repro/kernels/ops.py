@@ -13,6 +13,8 @@ import jax.numpy as jnp
 
 from . import compress as _cmp
 from . import flash_attention as _fa
+from . import node_conv as _nc
+from . import node_norm as _nn
 from . import qg_update as _qg
 from . import ssd_scan as _ssd
 
@@ -79,6 +81,48 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
         q, k_pages, v_pages, block_tables, lengths,
         window=window, softcap=softcap,
         interpret=_default_interpret() if interpret is None else interpret)
+
+
+def node_kernels_native() -> bool:
+    """Whether the node-batched kernels run natively here (on a TPU).
+    Elsewhere their jnp oracle runs slower than ``jax.vmap`` of the
+    per-node model, so the models offer the node-batched path only where
+    this holds (DESIGN.md §15)."""
+    return not _default_interpret()
+
+
+def _node_impl(impl):
+    """The node-batched kernels on a TPU, their jnp oracles elsewhere."""
+    return ("ref" if _default_interpret() else "pallas") if impl is None \
+        else impl
+
+
+def node_mxu_dtype():
+    """The MXU operand dtype of the node-batched convolution on a TPU: one
+    bfloat16 pass at JAX's default matmul precision, as a float32
+    ``lax.conv`` gets there; float32 at ``Precision.HIGHEST`` whenever
+    ``jax.default_matmul_precision`` asks for more than that."""
+    asked = jax.config.jax_default_matmul_precision
+    return (jnp.bfloat16 if asked in (None, "default", "fastest", "bfloat16")
+            else jnp.float32)
+
+
+def node_conv2d(x, w, *, height, width, stride=1, impl=None):
+    """Model entry of the node-batched convolution (``x[n, I, B*H*W]``,
+    ``w[n, k, k, I, O]``).  ``impl=None`` picks by platform, as
+    ``fused='auto'`` does: the Pallas kernels on a TPU, with operands in
+    :func:`node_mxu_dtype`, the float32 jnp oracle elsewhere."""
+    impl = _node_impl(impl)
+    mxu = node_mxu_dtype() if impl == "pallas" else jnp.float32
+    return _nc.conv2d(x, w, height=height, width=width, stride=stride,
+                      impl=impl, mxu_dtype=mxu, interpret=False)
+
+
+def node_evonorm(x, v, scale, bias, *, hw, impl=None):
+    """Model entry of the node-batched EvoNorm-S0 (``x[n, C, B*hw]``):
+    ``impl=None`` picks the Pallas kernels on a TPU, the jnp oracle
+    elsewhere."""
+    return _nn.node_evonorm(x, v, scale, bias, hw=hw, impl=_node_impl(impl))
 
 
 def ssd_scan(x, dt, a, b, c, d_skip, *, chunk=128, interpret=None):

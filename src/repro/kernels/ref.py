@@ -171,3 +171,89 @@ def ssd_scan_ref(x, dt, a, b, c, *, initial_state=None):
           jnp.moveaxis(c.astype(jnp.float32), 1, 0))
     hfin, ys = jax.lax.scan(step, h0, xs)
     return jnp.moveaxis(ys, 0, 1).astype(x.dtype), hfin
+
+
+# ---------------------------------------------------------------------------
+# node_conv — node-batched convolution taps in the channel-major layout
+# ---------------------------------------------------------------------------
+
+def node_conv_stack_ref(x, *, height: int, width: int, ksize: int):
+    """``x[n, I, B*H*W]`` -> ``[n, taps*I, B*H*W]``: row ``t*I + i`` is
+    ``x[i, m + dh*W + dw]`` for tap ``t = (dh, dw)`` (row-major over the
+    window), zero where the tap leaves the image."""
+    if ksize == 1:
+        return x
+    m = jnp.arange(x.shape[-1])
+    col, row = m % width, (m // width) % height
+    pieces = []
+    for dh in (-1, 0, 1):
+        for dw in (-1, 0, 1):
+            valid = ((row + dh >= 0) & (row + dh < height)
+                     & (col + dw >= 0) & (col + dw < width))
+            shifted = jnp.roll(x, -(dh * width + dw), axis=-1)
+            pieces.append(jnp.where(valid, shifted, 0.0))
+    return jnp.concatenate(pieces, axis=1)
+
+
+def node_conv_down_ref(y, *, height: int, width: int, offset: int):
+    """Every second row and column of each image from ``offset``:
+    ``[n, C, B*H*W]`` -> ``[n, C, B*(H/2)*(W/2)]``."""
+    n, c, m = y.shape
+    y = y.reshape(n, c, m // (height * width), height // 2, 2, width // 2, 2)
+    return y[:, :, :, :, offset, :, offset].reshape(n, c, m // 4)
+
+
+def node_conv_up_ref(v, *, height: int, width: int, offset: int):
+    """The transpose of :func:`node_conv_down_ref`: zeros elsewhere."""
+    n, c, m4 = v.shape
+    b = 4 * m4 // (height * width)
+    full = jnp.zeros((n, c, b, height // 2, 2, width // 2, 2), v.dtype)
+    full = full.at[:, :, :, :, offset, :, offset].set(
+        v.reshape(n, c, b, height // 2, width // 2))
+    return full.reshape(n, c, 4 * m4)
+
+
+def node_conv_taps_ref(a, x, *, height: int, width: int, ksize: int,
+                       mxu_dtype=jnp.float32, resample=None, offset: int = 0):
+    """``y[n, O, M] = a[n, O, taps*I] @ stack(x)``, operands rounded to
+    ``mxu_dtype`` and accumulated in float32; ``resample='down'`` keeps
+    the output's every second row and column, ``'up'`` spreads ``x`` from
+    that resolution first (``height, width``: the full resolution)."""
+    rs = dict(height=height, width=width, offset=offset)
+    if resample == "up":
+        x = node_conv_up_ref(x, **rs)
+    cols = node_conv_stack_ref(x, height=height, width=width, ksize=ksize)
+    y = jnp.einsum("nok,nkm->nom", a.astype(mxu_dtype),
+                   cols.astype(mxu_dtype), preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+    return node_conv_down_ref(y, **rs) if resample == "down" else y
+
+
+def node_conv_dw_ref(x, g, *, height: int, width: int, ksize: int,
+                     mxu_dtype=jnp.float32, resample=None, offset: int = 0):
+    """``dA[n, O, taps*I] = g[n, O, M] @ stack(x)^T`` (``resample='up'``:
+    ``g`` at the stride-2 resolution)."""
+    if resample == "up":
+        g = node_conv_up_ref(g, height=height, width=width, offset=offset)
+    cols = node_conv_stack_ref(x, height=height, width=width, ksize=ksize)
+    return jnp.einsum("nom,nkm->nok", g.astype(mxu_dtype),
+                      cols.astype(mxu_dtype),
+                      preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+# ---------------------------------------------------------------------------
+# node_norm — EvoNorm-S0 over a node block in the channel-major layout
+# ---------------------------------------------------------------------------
+
+def node_evonorm_ref(x, v, scale, bias, *, hw: int, groups: int = 2,
+                     eps: float = 1e-5):
+    """``x[n, C, B*hw]``, per-channel ``v, scale, bias[n, C]``: EvoNorm-S0
+    with statistics per node, image and channel group."""
+    n, c, m = x.shape
+    xg = x.reshape(n, groups, c // groups, m // hw, hw)
+    std = jnp.sqrt(jnp.var(xg, axis=(2, 4), keepdims=True) + eps)
+    col = lambda p: p[:, :, None]
+    num = x * jax.nn.sigmoid(col(v) * x)
+    y = num / jnp.broadcast_to(std, xg.shape).reshape(n, c, m)
+    return y * col(scale) + col(bias)

@@ -149,6 +149,85 @@ def apply_resnet20(params, state, x, *, norm: str = "evonorm",
 
 
 # ---------------------------------------------------------------------------
+# ResNet-20 over a block of nodes at once (DESIGN.md §15)
+# ---------------------------------------------------------------------------
+
+NODE_BATCHED_NORMS = ("evonorm", "gn")
+
+
+def node_batched_serves(norm: str, hw: int) -> bool:
+    """Whether :func:`apply_resnet20_nodes` serves this model here: a norm
+    it has, ``hw x hw`` inputs whose stride-2 layers see even sizes (``hw``
+    a multiple of 4), and the Pallas kernels native on this platform (off
+    a TPU their jnp oracle runs slower than ``jax.vmap``; DESIGN.md §15)."""
+    from repro.kernels import ops
+    return (norm in NODE_BATCHED_NORMS and hw % 4 == 0
+            and ops.node_kernels_native())
+
+
+def _norm_nodes(norm: str, p, x, batch: int, impl, groups=2, eps=1e-5):
+    """``_apply_norm`` for ``x[n, C, B*H*W]``: statistics per node, image
+    and group, over the group's channels and the image's pixels.  EvoNorm
+    runs through ``kernels.node_norm``; GroupNorm in XLA."""
+    from repro.kernels import ops
+    n, c, m = x.shape
+    if norm == "evonorm":
+        return ops.node_evonorm(x, p["v"], p["scale"], p["bias"],
+                                hw=m // batch, impl=impl)
+    if norm == "gn":
+        xg = x.reshape(n, groups, c // groups, batch, m // batch)
+        mean = jnp.mean(xg, axis=(2, 4), keepdims=True)
+        var = jnp.var(xg, axis=(2, 4), keepdims=True)
+        y = ((xg - mean) * jax.lax.rsqrt(var + eps)).reshape(n, c, m)
+        return y * p["scale"][:, :, None] + p["bias"][:, :, None]
+    raise ValueError(f"node-batched ResNet-20: norm {norm!r} "
+                     f"(one of {NODE_BATCHED_NORMS})")
+
+
+def apply_resnet20_nodes(params, x, *, norm: str = "evonorm",
+                         impl: str | None = None):
+    """``apply_resnet20`` for every node of a block at once: ``params``
+    node-stacked ``[n, ...]``, ``x[n, B, H, W, 3]`` -> logits ``[n, B,
+    classes]``.  Activations stay channel-major, ``[n, C, B*H*W]``, and
+    every convolution runs through ``kernels.node_conv`` (Pallas on a TPU,
+    its jnp oracle elsewhere).  EvoNorm-S0 and GroupNorm only: they keep no
+    running statistics, so train and eval are the same function."""
+    from repro.kernels import ops
+    n, b, hh, ww, cin = x.shape
+    h = jnp.transpose(x, (0, 4, 1, 2, 3)).reshape(n, cin, b * hh * ww)
+
+    def conv(v, w, size, stride=1):
+        return ops.node_conv2d(v, w, height=size, width=size, stride=stride,
+                               impl=impl)
+
+    def norm_(v, p):
+        return _norm_nodes(norm, p, v, b, impl)
+
+    size = hh
+    h = norm_(conv(h, params["stem"], size), params["stem_norm"])
+    if norm != "evonorm":
+        h = jax.nn.relu(h)
+    for s_idx in range(3):
+        for b_idx in range(3):
+            blk = params[f"s{s_idx}b{b_idx}"]
+            stride = 2 if (s_idx > 0 and b_idx == 0) else 1
+            y = norm_(conv(h, blk["conv1"], size, stride), blk["norm1"])
+            if norm != "evonorm":
+                y = jax.nn.relu(y)
+            sc = h if "proj" not in blk else conv(h, blk["proj"], size,
+                                                  stride)
+            size //= stride
+            y = norm_(conv(y, blk["conv2"], size), blk["norm2"])
+            h = jax.nn.relu(y + sc) if norm != "evonorm" else y + sc
+    pooled = jnp.mean(h.reshape(n, h.shape[1], b, size * size), axis=-1)
+    # the head as a product and a sum over channels: a batched dot this
+    # small is lowered on a TPU as a convolution over the node axis again
+    logits = jnp.sum(pooled[:, :, :, None] * params["head"][:, :, None, :],
+                     axis=1)
+    return logits + params["head_b"][:, None, :]
+
+
+# ---------------------------------------------------------------------------
 # VGG-11 (width factor 1/2, no normalization — Table 1 bottom)
 # ---------------------------------------------------------------------------
 

@@ -179,12 +179,33 @@ class Runtime:
     def _stage_compute(self, state, batch, rng, n):
         """Pipeline stage 2 — per-node loss/grad on this backend's layout:
         node-stacked ``[n, ...]`` leaves (vmap) or local blocks inside
-        shard_map (sharded/hybrid)."""
+        shard_map (sharded/hybrid).
+
+        The local block takes the trainer's node-batched loss where the
+        model offers one (DESIGN.md §15): the gradient of the block's summed
+        loss is every node's own gradient, since node ``i``'s loss reads
+        only its own parameters.  Otherwise ``jax.vmap`` of the per-node
+        gradient.  The path taken is counted at trace time."""
+        from repro.telemetry import trace
         rngs = self._node_rngs(rng, n)
-        grad_fn = jax.value_and_grad(self.trainer.loss_fn, has_aux=True)
+        nodes_fn = self.trainer.loss_nodes_fn
         with jax.named_scope("tm/grad"):
-            (loss, (new_ms, metrics)), grads = jax.vmap(grad_fn)(
-                state.params, state.model_state, batch, rngs)
+            if nodes_fn is not None:
+                trace.count("tm/grad/node_batched")
+
+                def block_loss(params):
+                    loss, aux = nodes_fn(params, state.model_state, batch,
+                                         rngs)
+                    return jnp.sum(loss), (loss, aux)
+
+                (_, (loss, (new_ms, metrics))), grads = jax.value_and_grad(
+                    block_loss, has_aux=True)(state.params)
+            else:
+                trace.count("tm/grad/vmap")
+                grad_fn = jax.value_and_grad(self.trainer.loss_fn,
+                                             has_aux=True)
+                (loss, (new_ms, metrics)), grads = jax.vmap(grad_fn)(
+                    state.params, state.model_state, batch, rngs)
         return loss, new_ms, metrics, grads
 
     def _stage_finish_mix(self, state, grads, w, lr, rng, mix_mask, inflight,

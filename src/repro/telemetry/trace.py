@@ -26,6 +26,8 @@ The host side, here:
     also adds its ``perf_counter`` seconds and a count under its name, and
     the compile counter listens.  One registry per process, because JAX's
     monitoring listeners are process-wide.
+  * :func:`count` — a named counter in the same registry, for facts of a
+    trace (which gradient path a step compiled with).
   * :class:`StepTimer` — host wall-clock per dispatched step, kept in a
     fixed-size ring buffer with percentile summaries (p50/p90/p99).  The
     recorder drives it; its summary lands in ``Result.telemetry``.
@@ -37,7 +39,7 @@ import time
 import jax
 
 __all__ = ["host_span", "step_span", "CompileLog", "Registry", "enable",
-           "disable", "totals", "reset", "StepTimer"]
+           "disable", "count", "totals", "reset", "StepTimer"]
 
 # the compile phases JAX reports, by the name they are counted under
 COMPILE_EVENTS = {
@@ -122,10 +124,12 @@ class CompileLog:
 
 
 class Registry:
-    """Seconds and counts of host spans by name, and the compile counter."""
+    """Seconds and counts of host spans by name, named counters, and the
+    compile counter."""
 
     def __init__(self):
         self.spans: dict = {}         # name -> [count, seconds]
+        self.counters: dict = {}      # name -> count
         self.compiles = CompileLog()
 
     def add(self, name: str, seconds: float) -> None:
@@ -133,13 +137,18 @@ class Registry:
         entry[0] += 1
         entry[1] += seconds
 
+    def count(self, name: str) -> None:
+        self.counters[name] = self.counters.get(name, 0) + 1
+
     def reset(self) -> None:
         self.spans.clear()
+        self.counters.clear()
         self.compiles.reset()
 
     def totals(self) -> dict:
         return {"spans": {k: {"count": n, "s": s}
                           for k, (n, s) in self.spans.items()},
+                "counters": dict(self.counters),
                 "compile": self.compiles.totals()}
 
 
@@ -169,9 +178,17 @@ def disable() -> None:
             reg.compiles.on_time_span)
 
 
+def count(name: str) -> None:
+    """Add one to the registry's counter ``name`` (nothing when tracing is
+    off).  The runtimes count here, at trace time, which gradient path each
+    compiled step took (``tm/grad/node_batched`` or ``tm/grad/vmap``)."""
+    if _registry is not None:
+        _registry.count(name)
+
+
 def totals() -> dict:
-    """``{"spans": {name: {count, s}}, "compile": {...}}``; ``{}`` when
-    tracing is off."""
+    """``{"spans": {name: {count, s}}, "counters": {name: count},
+    "compile": {...}}``; ``{}`` when tracing is off."""
     return {} if _registry is None else _registry.totals()
 
 

@@ -125,6 +125,12 @@ class DecentralizedTrainer:
                                    # client sampling / churn / stragglers
                                    # (DESIGN.md §11).  None = full
                                    # participation, the exact default graph.
+    loss_nodes_fn: Optional[Callable] = None  # loss_fn over a block of
+                                   # nodes at once: (params[b], mstate[b],
+                                   # batch[b], rngs[b]) -> (loss[b],
+                                   # (mstate[b], metrics)); the runtimes
+                                   # take it for every local block
+                                   # (DESIGN.md §15)
 
     def __post_init__(self):
         if getattr(self.optimizer, "fused", "off") not in ("pallas", "off",

@@ -22,9 +22,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import compress, flash_attention, qg_update, ssd_scan
+from repro.kernels import (compress, flash_attention, node_conv, node_norm,
+                           qg_update, ssd_scan)
 
 PACKED = 1_100_000          # ~1.1M-element packed optimizer buffer
+NODES, IMAGES = 16, 32      # the ResNet-20 ring-16 cell: 16 nodes x 32 images
 
 
 @pytest.fixture(scope="module")
@@ -91,6 +93,65 @@ def _cases(sh):
                 q, k, v, bt, ln, interpret=False),
             (s((8, 1, 32, 64)), s((128, 4, 16, 64)), s((128, 4, 16, 64)),
              s((8, 16), i32), s((8,), i32))),
+        # ResNet-20 stage 0 (16 channels at 32x32) over a 16-node block
+        "node_conv_fwd": (
+            lambda a, x: node_conv.conv_taps(
+                a, x, height=32, width=32, ksize=3, interpret=False),
+            (s((NODES, 16, 144)), s((NODES, 16, IMAGES * 1024)))),
+        "node_conv_dx": (
+            lambda a, g: node_conv.conv_taps(
+                a, g, height=32, width=32, ksize=3, name="node_conv_dx",
+                interpret=False),
+            (s((NODES, 16, 144)), s((NODES, 16, IMAGES * 1024)))),
+        "node_conv_dw": (
+            lambda x, g: node_conv.conv_taps_dw(
+                x, g, height=32, width=32, ksize=3, interpret=False),
+            (s((NODES, 16, IMAGES * 1024)), s((NODES, 16, IMAGES * 1024)))),
+        # stage 1's first convolution: stride 2 from 32x32, the taps
+        # selected on the MXU ('down') and the gradient spread back ('up')
+        "node_conv_fwd_stride2": (
+            lambda a, x: node_conv.conv_taps(
+                a, x, height=32, width=32, ksize=3, resample="down",
+                offset=1, interpret=False),
+            (s((NODES, 32, 144)), s((NODES, 16, IMAGES * 1024)))),
+        "node_conv_dw_stride2": (
+            lambda x, g: node_conv.conv_taps_dw(
+                x, g, height=32, width=32, ksize=3, resample="up",
+                offset=1, interpret=False),
+            (s((NODES, 16, IMAGES * 1024)), s((NODES, 32, IMAGES * 256)))),
+        # float32 MXU operands (under default_matmul_precision 'highest')
+        "node_conv_fwd_f32": (
+            lambda a, x: node_conv.conv_taps(
+                a, x, height=32, width=32, ksize=3, mxu_dtype=f32,
+                interpret=False),
+            (s((NODES, 16, 144)), s((NODES, 16, IMAGES * 1024)))),
+        "node_conv_dw_f32_stride2": (
+            lambda x, g: node_conv.conv_taps_dw(
+                x, g, height=32, width=32, ksize=3, mxu_dtype=f32,
+                resample="up", offset=1, interpret=False),
+            (s((NODES, 16, IMAGES * 1024)), s((NODES, 32, IMAGES * 256)))),
+        # stage 0 (16 channels at 32x32: 8 images a tile)
+        "node_evonorm_fwd_stage0": (
+            lambda x, v, sc, b: node_norm.evonorm_fwd(
+                x, v, sc, b, hw=1024, interpret=False),
+            (s((NODES, 16, IMAGES * 1024)), s((NODES, 16)), s((NODES, 16)),
+             s((NODES, 16)))),
+        "node_evonorm_bwd_stage0": (
+            lambda x, g, v, sc: node_norm.evonorm_bwd(
+                x, g, v, sc, hw=1024, interpret=False),
+            (s((NODES, 16, IMAGES * 1024)), s((NODES, 16, IMAGES * 1024)),
+             s((NODES, 16)), s((NODES, 16)))),
+        # stage 2 (64 channels at 8x8, 64 lanes an image)
+        "node_evonorm_fwd": (
+            lambda x, v, sc, b: node_norm.evonorm_fwd(
+                x, v, sc, b, hw=64, interpret=False),
+            (s((NODES, 64, IMAGES * 64)), s((NODES, 64)), s((NODES, 64)),
+             s((NODES, 64)))),
+        "node_evonorm_bwd": (
+            lambda x, g, v, sc: node_norm.evonorm_bwd(
+                x, g, v, sc, hw=64, interpret=False),
+            (s((NODES, 64, IMAGES * 64)), s((NODES, 64, IMAGES * 64)),
+             s((NODES, 64)), s((NODES, 64)))),
         # mamba2-130m: 24 heads of P=64, d_state 128, chunk 128; 2 x 512
         "ssd_scan_bh": (
             lambda x, dt, adt, b, c: ssd_scan.ssd_scan_bh(
@@ -102,14 +163,25 @@ def _cases(sh):
 
 KERNELS = ["fused_halfstep", "fused_qg_buffer", "gamma_correct",
            "threshold_mask", "quantize_dequantize", "flash_attention_bf16",
-           "flash_attention_fp32", "paged_decode_attention", "ssd_scan_bh"]
+           "flash_attention_fp32", "paged_decode_attention", "ssd_scan_bh",
+           "node_conv_fwd", "node_conv_dx", "node_conv_dw",
+           "node_conv_fwd_stride2", "node_conv_dw_stride2",
+           "node_conv_fwd_f32", "node_conv_dw_f32_stride2",
+           "node_evonorm_fwd_stage0", "node_evonorm_bwd_stage0",
+           "node_evonorm_fwd", "node_evonorm_bwd"]
 
 
 # the name each kernel passes to ``pallas_call``: the last scope of its
 # op's ``op_name`` in compiled programs (``tf_op`` in device traces)
 KERNEL_NAME = {"flash_attention_bf16": "flash_attention",
                "flash_attention_fp32": "flash_attention",
-               "ssd_scan_bh": "ssd_scan"}
+               "ssd_scan_bh": "ssd_scan",
+               "node_conv_fwd_stride2": "node_conv_fwd",
+               "node_conv_dw_stride2": "node_conv_dw",
+               "node_conv_fwd_f32": "node_conv_fwd",
+               "node_conv_dw_f32_stride2": "node_conv_dw",
+               "node_evonorm_fwd_stage0": "node_evonorm_fwd",
+               "node_evonorm_bwd_stage0": "node_evonorm_bwd"}
 
 
 @pytest.mark.parametrize("name", KERNELS)
@@ -120,3 +192,30 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in text, name
     kernel = KERNEL_NAME.get(name, name)    # the op's scope in the trace
     assert re.search(rf'op_name="[^"]*/{kernel}/pallas_call"', text), kernel
+
+
+def test_node_batched_resnet20_gradient_compiles_for_v5e(one_chip):
+    """The node-batched ResNet-20 gradient at the cell's shapes (16 nodes x
+    32 images of 32x32x3) compiles with every convolution and EvoNorm in
+    the named kernels, and no convolution left in XLA: ``jax.vmap`` of the
+    per-node model lowers each one as a convolution whose window runs over
+    the node axis (``size=...x16``)."""
+    from repro.models import resnet
+
+    p1, _ = jax.eval_shape(lambda k: resnet.init_resnet20(k),
+                           jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (NODES,) + a.shape, a.dtype, sharding=one_chip), p1)
+    x = jax.ShapeDtypeStruct((NODES, IMAGES, 32, 32, 3), jnp.float32,
+                             sharding=one_chip)
+
+    def block_grad(p, xb):
+        return jax.grad(lambda q: jnp.sum(resnet.apply_resnet20_nodes(
+            q, xb, impl="pallas")))(p)
+
+    text = jax.jit(block_grad).lower(params, x).compile().as_text()
+    assert not re.search(r"= \S+ convolution\(", text)
+    for kernel in ("node_conv_fwd", "node_conv_dx", "node_conv_dw",
+                   "node_evonorm_fwd", "node_evonorm_bwd"):
+        assert re.search(rf'op_name="[^"]*/{kernel}/pallas_call"', text), \
+            kernel
