@@ -214,12 +214,17 @@ def _transformer(spec, task) -> ModelBundle:
     def init_fn(key):
         return tf.init_lm(key, cfg), {}
 
+    # each period of the layer scan is rematerialised in the backward pass
+    # (DESIGN.md §16): on a v5e one mamba2-130m node's gradient at 4x512
+    # tokens takes 44 ms rematerialised and 63 ms with its activations
+    # kept, and at 4x2048 only the rematerialised step fits the chip
     def loss_fn(params, _ms, batch, _rng):
         (toks,) = batch
         b = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
         if img is not None:
             b["image_embeds"] = jnp.broadcast_to(
                 img, (toks.shape[0],) + img.shape)
-        return tf.train_loss(params, b, cfg, **fwd_kw), ({}, {})
+        return tf.train_loss(params, b, cfg, remat="full",
+                             **fwd_kw), ({}, {})
 
     return ModelBundle(init_fn, loss_fn, eval_fn=None)

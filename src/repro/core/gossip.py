@@ -40,6 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.telemetry import trace
+
 from .topology import Topology
 
 PyTree = Any
@@ -70,6 +72,13 @@ __all__ = [
     "mix_leaf_dense_block",
     "make_block_mix_fn",
 ]
+
+
+def tree_bytes(tree) -> int:
+    """Bytes of the array leaves of ``tree`` (arrays, tracers or shape
+    structs)."""
+    return sum(int(np.prod(l.shape)) * jnp.dtype(l.dtype).itemsize
+               for l in jax.tree.leaves(tree) if hasattr(l, "shape"))
 
 
 def mix_leaf_dense(w: jax.Array, x: jax.Array) -> jax.Array:
@@ -198,6 +207,18 @@ class PhaseSchedule:
             return self.n * (self.n - 1)
         return sum(len(perm) for perm, _ in self.rounds)
 
+    @property
+    def most_sent(self) -> int:
+        """Most model messages one node sends in this phase: ``n - 1`` in
+        the all-gather fallback, else the rounds in which it is a sender."""
+        if self.dense:
+            return self.n - 1
+        sent = np.zeros(self.n, int)
+        for perm, _ in self.rounds:
+            for src, _ in perm:
+                sent[src] += 1
+        return int(sent.max(initial=0))
+
 
 @dataclasses.dataclass(frozen=True)
 class GossipSchedule:
@@ -224,6 +245,12 @@ class GossipSchedule:
         """What the all-gather baseline ships per step: every node reads
         every other node's model."""
         return float(self.n * (self.n - 1))
+
+    def bytes_sent(self, tree) -> int:
+        """Bytes the busiest node sends in one gossip step of its ``tree``
+        (its own shard), averaged over the phases."""
+        sent = np.mean([p.most_sent for p in self.phases])
+        return int(round(float(sent) * tree_bytes(tree)))
 
 
 def _compile_phase(w: np.ndarray, *, dense_threshold: float) -> PhaseSchedule:
@@ -374,13 +401,20 @@ def make_local_mix_fn(schedule: GossipSchedule | None, *, axis_name: str,
     compiled schedule at phase ``t`` executed directly on the local shards
     (NO shard_map re-entry); sites that pass any other [n, n] matrix — or
     every site when ``schedule`` is None (forced-dense gossip) — get the
-    all-gather row contraction of the matrix they actually asked for."""
+    all-gather row contraction of the matrix they actually asked for.
+    Each call counts, at trace time, the bytes this device sends
+    (``tm/gossip/bytes`` in ``repro.telemetry.trace``): the schedule's
+    :meth:`GossipSchedule.bytes_sent`, or ``n - 1`` copies of its shard in
+    the all-gather."""
 
     def mix_fn(w, tree):
         if schedule is None or w is not w_ref:
+            n = jnp.shape(w)[0]
+            trace.count("tm/gossip/bytes", (n - 1) * tree_bytes(tree))
             return jax.tree.map(
                 functools.partial(mix_leaf_dense_local, w,
                                   axis_name=axis_name), tree)
+        trace.count("tm/gossip/bytes", schedule.bytes_sent(tree))
         return jax.tree.map(
             lambda x: apply_schedule_local(x, schedule, t,
                                            axis_name=axis_name), tree)

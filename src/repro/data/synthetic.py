@@ -40,6 +40,23 @@ def make_classification(
     return x.astype(np.float32), labels
 
 
+def _count_below(cum: np.ndarray, rows: np.ndarray, u: np.ndarray
+                 ) -> np.ndarray:
+    """``(cum[rows] < u[:, None]).sum(axis=1)`` for rows of ``cum`` that do
+    not decrease (cumulative sums of non-negative numbers), by a binary
+    search per row: log2(width) gathers of one entry a row in place of a
+    pass over the whole row."""
+    width = cum.shape[1]
+    lo = np.zeros(len(rows), np.int64)
+    hi = np.full(len(rows), width, np.int64)
+    for _ in range(width.bit_length()):
+        mid = (lo + hi) // 2
+        below = cum[rows, np.minimum(mid, width - 1)] < u
+        lo = np.where((lo < hi) & below, mid + 1, lo)
+        hi = np.where((lo < hi) & ~below, mid, hi)
+    return lo
+
+
 def make_lm_domains(
     n_domains: int = 8, *, vocab: int = 512, seq_len: int = 128,
     n_seq_per_domain: int = 256, skew: float = 8.0, seed: int = 0,
@@ -59,8 +76,7 @@ def make_lm_domains(
         toks[:, 0] = cur
         u = rng.random(size=(n_seq_per_domain, seq_len))
         for t in range(seq_len):
-            cur = (cum[cur] < u[:, t:t + 1]).sum(axis=1)
-            cur = np.minimum(cur, vocab - 1)
+            cur = np.minimum(_count_below(cum, cur, u[:, t]), vocab - 1)
             toks[:, t + 1] = cur
         all_tokens.append(toks)
         all_domain.append(np.full(n_seq_per_domain, d, np.int32))

@@ -6,6 +6,7 @@ HLO compact — essential for 512-host-device dry-run compiles).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -93,9 +94,10 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> ja
 # loss
 # ---------------------------------------------------------------------------
 
-def cross_entropy(logits: jax.Array, labels: jax.Array,
-                  vocab_valid: int | None = None) -> jax.Array:
-    """Mean token cross-entropy; padded vocab rows (>= vocab_valid) masked."""
+def token_nll(logits: jax.Array, labels: jax.Array,
+              vocab_valid: int | None = None) -> jax.Array:
+    """Each token's cross-entropy; padded vocab rows (>= vocab_valid)
+    masked."""
     logits = logits.astype(jnp.float32)
     if vocab_valid is not None and vocab_valid < logits.shape[-1]:
         neg = jnp.finfo(jnp.float32).min
@@ -103,4 +105,84 @@ def cross_entropy(logits: jax.Array, labels: jax.Array,
         logits = jnp.where(pad, neg, logits)
     lse = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - gold)
+    return lse - gold
+
+
+def cross_entropy(logits: jax.Array, labels: jax.Array,
+                  vocab_valid: int | None = None) -> jax.Array:
+    """Mean token cross-entropy; padded vocab rows (>= vocab_valid) masked."""
+    return jnp.mean(token_nll(logits, labels, vocab_valid))
+
+
+def blocked_cross_entropy(h: jax.Array, head: jax.Array, labels: jax.Array,
+                          vocab_valid: int | None = None, *,
+                          cap: float = 0.0, block: int = 1024) -> jax.Array:
+    """``cross_entropy(softcap(h @ head, cap), labels, vocab_valid)`` with
+    the head applied ``block`` tokens at a time.
+
+    ``h`` is ``[..., d]`` (the final-normed hidden state), ``head`` is
+    ``[d, V]``.  The tokens are scanned in blocks (the last one padded, its
+    padding left out of the sum), so no more than one block's fp32 logits
+    are live.  Differentiated, each block's gradient is taken where its
+    logits are made, in the forward pass: the pass keeps the hidden
+    state's gradient and accumulates the head's, and the backward pass
+    only scales them, so the logits are computed once.  Same logits and
+    the same masked mean as :func:`cross_entropy` on the whole
+    ``[tokens, V]`` logits."""
+    return _blocked_ce(h, head, labels, vocab_valid, cap, block)
+
+
+def _token_blocks(h, labels, block):
+    d = h.shape[-1]
+    t = h.size // d
+    block = min(block, t)
+    nb = -(-t // block)
+    pad = nb * block - t
+    hb = jnp.pad(h.reshape(t, d), ((0, pad), (0, 0))).reshape(nb, block, d)
+    lb = jnp.pad(labels.reshape(t), (0, pad)).reshape(nb, block)
+    real = (jnp.arange(nb * block) < t).reshape(nb, block)
+    return (hb, lb, real), t
+
+
+def _block_nll_sum(hb, head, lb, real, vocab_valid, cap):
+    logits = jnp.einsum("td,dv->tv", hb, head).astype(jnp.float32)
+    nll = token_nll(softcap(logits, cap), lb, vocab_valid)
+    return jnp.sum(jnp.where(real, nll, 0.0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _blocked_ce(h, head, labels, vocab_valid, cap, block):
+    blocks, t = _token_blocks(h, labels, block)
+
+    def one(total, xs):
+        hb, lb, real = xs
+        return total + _block_nll_sum(hb, head, lb, real, vocab_valid,
+                                      cap), None
+
+    total, _ = jax.lax.scan(one, jnp.zeros((), jnp.float32), blocks)
+    return total / t
+
+
+def _blocked_ce_fwd(h, head, labels, vocab_valid, cap, block):
+    blocks, t = _token_blocks(h, labels, block)
+    grad = jax.value_and_grad(_block_nll_sum, argnums=(0, 1))
+
+    def one(carry, xs):
+        total, dhead = carry
+        hb, lb, real = xs
+        s, (dhb, dheadb) = grad(hb, head, lb, real, vocab_valid, cap)
+        return (total + s, dhead + dheadb), dhb
+
+    zero = (jnp.zeros((), jnp.float32), jnp.zeros_like(head))
+    (total, dhead), dh = jax.lax.scan(one, zero, blocks)
+    dh = dh.reshape(-1, h.shape[-1])[:t].reshape(h.shape)
+    return total / t, (dh / t, dhead / t)
+
+
+def _blocked_ce_bwd(vocab_valid, cap, block, res, g):
+    dh, dhead = res
+    return ((g * dh).astype(dh.dtype), (g * dhead).astype(dhead.dtype),
+            None)
+
+
+_blocked_ce.defvjp(_blocked_ce_fwd, _blocked_ce_bwd)

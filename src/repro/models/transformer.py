@@ -406,10 +406,13 @@ def _embed(params, tokens, cfg):
     return jnp.take(params["embed"], tokens, axis=0)
 
 
+def _head(params, cfg: ModelConfig):
+    return params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+
+
 def _logits(params, x, cfg: ModelConfig):
     h = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("...d,dv->...v", h, head)
+    logits = jnp.einsum("...d,dv->...v", h, _head(params, cfg))
     return layers.softcap(logits.astype(jnp.float32), cfg.logit_softcap)
 
 
@@ -435,10 +438,12 @@ def forward(params, tokens, cfg: ModelConfig, *, mode: str,
             unroll: bool = False, remat_attention: bool = False,
             cache_constraint=None, decode_lowp: bool = False,
             act_spec=None, repeat_kv: bool = False, head_spec=None,
-            moe_expert_spec=None, pages=None):
+            moe_expert_spec=None, pages=None, head: bool = True):
     """Shared driver. Returns (logits, aux_loss, new_cache).
 
     train:   tokens [B,S]   -> logits [B,S,Vp], aux, None
+             (``head=False``: the last layer's output [B,S,d] in place of
+             the logits, for a loss that applies the head itself)
     prefill: tokens [B,S]   -> logits [B,Vp] (last pos), aux, cache
     decode:  tokens [B,1]   -> logits [B,Vp], aux, cache
     paged:   tokens [B,C]   -> logits [B,Vp] (per-slot last valid), aux, pages
@@ -516,7 +521,7 @@ def forward(params, tokens, cfg: ModelConfig, *, mode: str,
         new_cache["tail"] = tuple(tail_caches)
 
     if mode == "train":
-        return _logits(params, x, cfg), aux_total, None
+        return (_logits(params, x, cfg) if head else x), aux_total, None
     if mode == "prefill":
         return _logits(params, x[:, -1], cfg), aux_total, new_cache
     if mode == "paged":
@@ -527,11 +532,27 @@ def forward(params, tokens, cfg: ModelConfig, *, mode: str,
     return _logits(params, x[:, 0], cfg), aux_total, new_cache
 
 
+# tokens per block of the training loss's head: one block's fp32 logits
+# (1024 x 50432 for mamba2-130m: 0.21 GB) stand in for the whole
+# [tokens, vocab] logits and their gradient
+HEAD_BLOCK = 1024
+
+
 def train_loss(params, batch, cfg: ModelConfig, **kw):
-    """batch: {tokens [B,S], labels [B,S], (image_embeds)} -> scalar loss."""
-    logits, aux, _ = forward(params, batch["tokens"], cfg, mode="train",
-                             img=batch.get("image_embeds"), **kw)
-    ce = layers.cross_entropy(logits, batch["labels"], cfg.vocab_size)
+    """batch: {tokens [B,S], labels [B,S], (image_embeds)} -> scalar loss.
+
+    The output head and the cross-entropy run ``HEAD_BLOCK`` tokens at a
+    time (``layers.blocked_cross_entropy``, under the ``tm/lm_head``
+    scope); ``kw`` goes to :func:`forward`
+    (``remat='full'`` rematerialises each period of the layer scan in the
+    backward pass)."""
+    x, aux, _ = forward(params, batch["tokens"], cfg, mode="train",
+                        img=batch.get("image_embeds"), head=False, **kw)
+    with jax.named_scope("tm/lm_head"):
+        h = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        ce = layers.blocked_cross_entropy(
+            h, _head(params, cfg), batch["labels"], cfg.vocab_size,
+            cap=cfg.logit_softcap, block=HEAD_BLOCK)
     return ce + aux
 
 

@@ -399,6 +399,12 @@ class Runtime:
             self._chunk_fns[collect] = self._build_chunk(collect)
         return self._chunk_fns[collect](state, batches, rng)
 
+    def init_state(self, build, *args):
+        """The TrainState ``build(*args)`` makes, placed where this backend
+        keeps it.  Here: built on the default device, then
+        :meth:`finalize_state`."""
+        return self.finalize_state(build(*args))
+
     def finalize_state(self, state):
         """Place a freshly initialized (host/replicated) TrainState where
         this backend wants it.  Identity for vmap; the sharded backend

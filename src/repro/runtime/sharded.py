@@ -149,6 +149,21 @@ class ShardedRuntime(Runtime):
 
         return jax.tree.map(put, tree)
 
+    def init_state(self, build, *args):
+        """Build the state with every node-stacked leaf born sharded over
+        the node axis: one jitted ``build`` whose outputs carry the layout
+        contract's shardings, so each device computes only its own nodes'
+        rows.  Built on one device and then split, the whole node stack
+        and its optimizer state pass through that device first (5.4 GB for
+        four mamba2-130m nodes).  A multi-process mesh places host values
+        instead (:meth:`finalize_state`)."""
+        if jax.process_count() > 1:
+            return super().init_state(build, *args)
+        out = jax.tree.map(
+            lambda l: NamedSharding(self.mesh, self._leaf_spec(l)),
+            jax.eval_shape(build, *args))
+        return jax.jit(build, out_shardings=out)(*args)
+
     def finalize_state(self, state):
         """Shard a freshly initialized TrainState over the node axis — after
         this, no device ever materializes the full node stack again.  On a

@@ -27,7 +27,8 @@ The host side, here:
     the compile counter listens.  One registry per process, because JAX's
     monitoring listeners are process-wide.
   * :func:`count` — a named counter in the same registry, for facts of a
-    trace (which gradient path a step compiled with).
+    trace (which gradient path a step compiled with, the gossip bytes it
+    sends).
   * :class:`StepTimer` — host wall-clock per dispatched step, kept in a
     fixed-size ring buffer with percentile summaries (p50/p90/p99).  The
     recorder drives it; its summary lands in ``Result.telemetry``.
@@ -137,8 +138,8 @@ class Registry:
         entry[0] += 1
         entry[1] += seconds
 
-    def count(self, name: str) -> None:
-        self.counters[name] = self.counters.get(name, 0) + 1
+    def count(self, name: str, amount: int = 1) -> None:
+        self.counters[name] = self.counters.get(name, 0) + amount
 
     def reset(self) -> None:
         self.spans.clear()
@@ -178,12 +179,14 @@ def disable() -> None:
             reg.compiles.on_time_span)
 
 
-def count(name: str) -> None:
-    """Add one to the registry's counter ``name`` (nothing when tracing is
-    off).  The runtimes count here, at trace time, which gradient path each
-    compiled step took (``tm/grad/node_batched`` or ``tm/grad/vmap``)."""
+def count(name: str, amount: int = 1) -> None:
+    """Add ``amount`` to the registry's counter ``name`` (nothing when
+    tracing is off).  The runtimes count here, at trace time, facts of each
+    compiled step: its gradient path (``tm/grad/node_batched`` or
+    ``tm/grad/vmap``) and the bytes one device sends in the gossip rounds
+    of a step (``tm/gossip/bytes``)."""
     if _registry is not None:
-        _registry.count(name)
+        _registry.count(name, amount)
 
 
 def totals() -> dict:

@@ -237,34 +237,38 @@ class DecentralizedTrainer:
     # -- init ---------------------------------------------------------------
     def init(self, key, init_fn) -> TrainState:
         """init_fn(key) -> (params, model_state); every node starts from the
-        SAME x^0 (the paper's setup).  The runtime places the state (the
-        sharded backend shards every node-stacked leaf over the node axis)."""
+        SAME x^0 (the paper's setup).  The runtime builds the node-stacked
+        state where it lives (``Runtime.init_state``: the sharded backend
+        builds each device's own nodes there, so no device ever holds the
+        whole node stack)."""
         params, mstate = init_fn(key)
         n = self.topology.n
-        stack = lambda tree: jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (n,) + x.shape).copy() if hasattr(
-                x, "shape") else x, tree)
-        params_n = stack(params)
-        mstate_n = stack(mstate)
-        comm_state = None
-        if self.comm is not None:
-            comm_state = self.comm.init_state(
-                self.optimizer, params_n, self._mixing[0])
-        mix_buf = None
-        if self.overlap != "none":
-            # t=0 exchange buffers: the trees each topology mix site would
-            # have contracted on the first step.  All nodes share x^0, so
-            # the first delayed correction is exactly zero.
-            from repro.runtime.overlap import capture_topology_mix_sites
-            mix_buf = capture_topology_mix_sites(
-                self.optimizer, params_n, self._mixing[0])
-        state = TrainState(params=params_n,
-                           opt_state=self.optimizer.init(params_n),
-                           model_state=mstate_n,
-                           t=jnp.zeros((), jnp.int32),
-                           comm_state=comm_state,
-                           mix_buf=mix_buf)
-        return self._runtime.finalize_state(state)
+
+        def build(params, mstate):
+            stack = lambda tree: jax.tree.map(
+                lambda x: jnp.broadcast_to(x, (n,) + x.shape).copy()
+                if hasattr(x, "shape") else x, tree)
+            params_n = stack(params)
+            comm_state = None
+            if self.comm is not None:
+                comm_state = self.comm.init_state(
+                    self.optimizer, params_n, self._mixing[0])
+            mix_buf = None
+            if self.overlap != "none":
+                # t=0 exchange buffers: the trees each topology mix site
+                # would have contracted on the first step.  All nodes share
+                # x^0, so the first delayed correction is exactly zero.
+                from repro.runtime.overlap import capture_topology_mix_sites
+                mix_buf = capture_topology_mix_sites(
+                    self.optimizer, params_n, self._mixing[0])
+            return TrainState(params=params_n,
+                              opt_state=self.optimizer.init(params_n),
+                              model_state=stack(mstate),
+                              t=jnp.zeros((), jnp.int32),
+                              comm_state=comm_state,
+                              mix_buf=mix_buf)
+
+        return self._runtime.init_state(build, params, mstate)
 
     # -- one jitted decentralized step ---------------------------------------
     def step(self, state: TrainState, batch: PyTree, rng,

@@ -96,3 +96,40 @@ def test_lm_domains_distinct():
         return m / m.sum()
     tv01 = 0.5 * np.abs(big(0) - big(1)).sum()
     assert tv01 > 0.3
+
+
+def _lm_domains_row_scan(n_domains, *, vocab, seq_len, n_seq_per_domain,
+                         skew=8.0, seed=0):
+    """The generator as first written: each next token by a pass over its
+    whole row of the cumulative transition table."""
+    rng = np.random.default_rng(seed)
+    tokens, domain = [], []
+    for d in range(n_domains):
+        cum = np.cumsum(rng.dirichlet(np.full(vocab, 1.0 / skew),
+                                      size=vocab), axis=1)
+        toks = np.empty((n_seq_per_domain, seq_len + 1), np.int32)
+        cur = rng.integers(0, vocab, size=n_seq_per_domain)
+        toks[:, 0] = cur
+        u = rng.random(size=(n_seq_per_domain, seq_len))
+        for t in range(seq_len):
+            cur = np.minimum((cum[cur] < u[:, t:t + 1]).sum(axis=1),
+                             vocab - 1)
+            toks[:, t + 1] = cur
+        tokens.append(toks)
+        domain.append(np.full(n_seq_per_domain, d, np.int32))
+    return np.concatenate(tokens), np.concatenate(domain)
+
+
+@pytest.mark.parametrize("kw", [
+    dict(n_domains=3, vocab=64, seq_len=32, n_seq_per_domain=16, seed=1),
+    dict(n_domains=2, vocab=4096, seq_len=16, n_seq_per_domain=64, seed=5),
+    dict(n_domains=2, vocab=1000, seq_len=40, n_seq_per_domain=7, seed=2,
+         skew=200.0),
+])
+def test_lm_domains_search_matches_row_scan(kw):
+    """The binary search over each transition row gives the very tokens the
+    row scan gave, so every seeded stream is unchanged."""
+    want = _lm_domains_row_scan(**kw)
+    got = make_lm_domains(**kw)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])

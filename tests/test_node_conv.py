@@ -363,7 +363,10 @@ ops.node_kernels_native = lambda: True   # as on a TPU
 sh, c_sh = first(s.replace(runtime="sharded"), mesh)
 ops.node_kernels_native = lambda: False  # per-node lax.conv
 vm, c_vm = first(s.replace(runtime="vmap"))
-assert c_sh == {"tm/grad/node_batched": 1}, c_sh
+grad_paths = {k: v for k, v in c_sh.items() if k.startswith("tm/grad/")}
+assert grad_paths == {"tm/grad/node_batched": 1}, c_sh
+# a ring of 2: each device sends its 272,970 fp32 parameters once a step
+assert c_sh["tm/gossip/bytes"] == 4 * 272_970, c_sh
 assert c_vm == {"tm/grad/vmap": 1}, c_vm
 for a, b in zip(jax.tree.leaves(sh.params), jax.tree.leaves(vm.params)):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
