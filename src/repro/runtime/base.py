@@ -92,6 +92,9 @@ class Runtime:
         # default path compiles exactly what it always did
         self._step_fns = {}
         self._chunk_fns = {}
+        # the step's ``record`` input, kept on the device by value (a bool,
+        # or a chunk's tuple of bools) so no step pays a host transfer for it
+        self._record_args = {}
         # non-donating probe fns for tm.gossip_wait_ms (built on first use)
         self._probe_fns = None
         from repro.telemetry.trace import StepTimer
@@ -252,13 +255,17 @@ class Runtime:
         return new_params, new_opt, new_comm, new_buf
 
     # -- the step math (shared by every backend) -----------------------------
-    def _step_math(self, state, batch, rng, collect: bool = False):
+    def _step_math(self, state, batch, rng, record, collect: bool = False):
         """One decentralized step on whatever layout the backend presents:
         node-stacked ``[n, ...]`` leaves (vmap) or local ``[b, ...]`` shards
         inside shard_map (sharded/hybrid).  Returns (new TrainState,
         metrics).  Orchestrates the launch_mix → compute → finish_mix
         pipeline above; the overlap mode's launch-stage collectives are
         emitted BEFORE the gradient computation in the trace.
+
+        ``record`` is a traced bool, replicated on every device: whether the
+        loop records this step's metrics.  Only then is ``consensus``
+        computed (:meth:`_consensus`); otherwise it reads NaN.
 
         ``collect`` is a TRACE-TIME flag: True adds the telemetry collectors
         (DESIGN.md §10) to this trace; False is the exact pre-telemetry
@@ -298,8 +305,7 @@ class Runtime:
         out_metrics = {
             "loss": self._node_mean_scalar(loss),
             "lr": lr,
-            "consensus": gossip.consensus_distance(
-                new_params, axis_name=self.axis_name),
+            "consensus": self._consensus(record, new_params),
             "grad_norm": jnp.sqrt(self._node_sum_scalar(sum(
                 jnp.sum(g.astype(jnp.float32) ** 2)
                 for g in jax.tree.leaves(grads))) / n),
@@ -323,6 +329,19 @@ class Runtime:
                 alive=u_loc, mix_buf_new=new_buf))
         return TrainState(new_params, new_opt, new_ms, state.t + 1,
                           new_comm, new_buf), out_metrics
+
+    def _consensus(self, record, params):
+        """``gossip.consensus_distance`` of ``params`` where ``record`` is
+        true, NaN where it is false.  Across chips the distance all-reduces
+        every parameter, and it reads the step's last result, so nothing
+        hides it: inside the conditional's true branch only the steps the
+        loop records pay for it."""
+        return jax.lax.cond(
+            record,
+            lambda p: gossip.consensus_distance(
+                p, axis_name=self.axis_name).astype(jnp.float32),
+            lambda p: jnp.full((), jnp.nan, jnp.float32),
+            params)
 
     def _telemetry_metrics(self, state, grads, new_params, new_opt,
                            new_comm, lr, n, alive=None,
@@ -359,45 +378,71 @@ class Runtime:
             vals = tel.collect(ctx)
         return {TM_PREFIX + k: v for k, v in vals.items()}
 
-    def _chunk_math(self, state, batches, rng, collect: bool = False):
+    def _chunk_math(self, state, batches, rng, record,
+                    collect: bool = False):
         """k steps fused under one ``lax.scan`` (the per-step rng stream is
-        split inside the scan exactly as the outer loop splits it)."""
-        def body(carry, batch):
+        split inside the scan exactly as the outer loop splits it);
+        ``record`` is the ``[k]`` mask of the steps the loop records."""
+        def body(carry, xs):
             st, r = carry
+            batch, rec = xs
             r, sub = jax.random.split(r)
-            st, metrics = self._step_math(st, batch, sub, collect=collect)
+            st, metrics = self._step_math(st, batch, sub, rec,
+                                          collect=collect)
             return (st, r), metrics
 
-        (state, rng), metrics = jax.lax.scan(body, (state, rng), batches)
+        (state, rng), metrics = jax.lax.scan(body, (state, rng),
+                                             (batches, record))
         return state, rng, metrics
 
     # -- backend surface ------------------------------------------------------
     def _build_step(self, collect: bool = False):
-        def step(state, batch, rng):
-            return self._step_math(state, batch, rng, collect=collect)
+        def step(state, batch, rng, record):
+            return self._step_math(state, batch, rng, record,
+                                   collect=collect)
 
         return jax.jit(step, donate_argnums=0)
 
     def _build_chunk(self, collect: bool = False):
-        def chunk(state, batches, rng):
-            return self._chunk_math(state, batches, rng, collect=collect)
+        def chunk(state, batches, rng, record):
+            return self._chunk_math(state, batches, rng, record,
+                                    collect=collect)
 
         return jax.jit(chunk, donate_argnums=0)
 
-    def step(self, state, batch, rng, collect: bool = False):
+    def _put_replicated(self, x):
+        """Place a host value every node reads (identity placement here;
+        the sharded override commits it replicated over the mesh)."""
+        return jnp.asarray(x)
+
+    def _record_arg(self, record):
+        """``record`` (a bool, or a chunk's tuple of bools) as the device
+        array the step takes, made once per value."""
+        arr = self._record_args.get(record)
+        if arr is None:
+            arr = self._record_args[record] = self._put_replicated(
+                np.asarray(record, bool))
+        return arr
+
+    def step(self, state, batch, rng, record: bool = True,
+             collect: bool = False):
         """One jitted step.  DONATES ``state``: the input buffers back the
         output state, so per-device memory holds one state, not two.
-        ``collect=True`` selects the telemetry-collecting trace (compiled
-        separately, on first use)."""
+        ``record`` says whether the step's metrics are read, and so whether
+        it computes ``consensus``.  ``collect=True`` selects the
+        telemetry-collecting trace (compiled separately, on first use)."""
         if collect not in self._step_fns:
             self._step_fns[collect] = self._build_step(collect)
-        return self._step_fns[collect](state, batch, rng)
+        return self._step_fns[collect](state, batch, rng,
+                                       self._record_arg(record))
 
-    def step_chunk(self, state, batches, rng, collect: bool = False):
-        """k fused steps in ONE dispatch; donates ``state`` like ``step``."""
+    def step_chunk(self, state, batches, rng, record, collect: bool = False):
+        """k fused steps in ONE dispatch; donates ``state`` like ``step``.
+        ``record`` is a tuple of k bools, one per step."""
         if collect not in self._chunk_fns:
             self._chunk_fns[collect] = self._build_chunk(collect)
-        return self._chunk_fns[collect](state, batches, rng)
+        return self._chunk_fns[collect](state, batches, rng,
+                                        self._record_arg(tuple(record)))
 
     def init_state(self, build, *args):
         """The TrainState ``build(*args)`` makes, placed where this backend

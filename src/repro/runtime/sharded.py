@@ -197,9 +197,9 @@ class ShardedRuntime(Runtime):
                                                 lambda idx: a[idx])
         return jax.device_put(x, sh)
 
-    def step_chunk(self, state, batches, rng, collect: bool = False):
+    def step_chunk(self, state, batches, rng, record, collect: bool = False):
         return super().step_chunk(state, batches, self._put_replicated(rng),
-                                  collect=collect)
+                                  record, collect=collect)
 
     # -- compilation: ONE shard_map per step / per chunk ----------------------
     def _shard(self, fn, in_specs, out_specs):
@@ -208,25 +208,26 @@ class ShardedRuntime(Runtime):
             manual_axes=frozenset({self.axis_name}))
 
     def _build_step(self, collect: bool = False):
-        def sharded_step(state, batch, rng):
+        def sharded_step(state, batch, rng, record):
             sspecs = self._specs(state)
             fn = self._shard(
-                lambda st, b, r: self._step_math(st, b, r, collect=collect),
-                in_specs=(sspecs, self._specs(batch), P()),
+                lambda st, b, r, rec: self._step_math(st, b, r, rec,
+                                                      collect=collect),
+                in_specs=(sspecs, self._specs(batch), P(), P()),
                 out_specs=(sspecs, P()))
-            return fn(state, batch, rng)
+            return fn(state, batch, rng, record)
 
         return jax.jit(sharded_step, donate_argnums=0)
 
     def _build_chunk(self, collect: bool = False):
-        def sharded_chunk(state, batches, rng):
+        def sharded_chunk(state, batches, rng, record):
             sspecs = self._specs(state)
             fn = self._shard(
-                lambda st, b, r: self._chunk_math(st, b, r, collect=collect),
-                in_specs=(sspecs, self._specs(batches, lead=1),
-                          P()),
+                lambda st, b, r, rec: self._chunk_math(st, b, r, rec,
+                                                       collect=collect),
+                in_specs=(sspecs, self._specs(batches, lead=1), P(), P()),
                 out_specs=(sspecs, P(), P()))
-            return fn(state, batches, rng)
+            return fn(state, batches, rng, record)
 
         return jax.jit(sharded_chunk, donate_argnums=0)
 

@@ -28,7 +28,7 @@ The host side, here:
     monitoring listeners are process-wide.
   * :func:`count` — a named counter in the same registry, for facts of a
     trace (which gradient path a step compiled with, the gossip bytes it
-    sends).
+    sends) and of a loop (the steps that compute the consensus metric).
   * :class:`StepTimer` — host wall-clock per dispatched step, kept in a
     fixed-size ring buffer with percentile summaries (p50/p90/p99).  The
     recorder drives it; its summary lands in ``Result.telemetry``.
@@ -184,7 +184,9 @@ def count(name: str, amount: int = 1) -> None:
     tracing is off).  The runtimes count here, at trace time, facts of each
     compiled step: its gradient path (``tm/grad/node_batched`` or
     ``tm/grad/vmap``) and the bytes one device sends in the gossip rounds
-    of a step (``tm/gossip/bytes``)."""
+    of a step (``tm/gossip/bytes``).  The training loops count here each
+    step they dispatch with ``record`` true, the steps that compute the
+    ``consensus`` metric (``tm/metrics/consensus``)."""
     if _registry is not None:
         _registry.count(name, amount)
 

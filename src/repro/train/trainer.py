@@ -38,6 +38,7 @@ the paper's local-statistics BN protocol.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable, Optional
 
@@ -49,10 +50,12 @@ from repro.comm.choco import CompressedGossip
 from repro.core import gossip
 from repro.core.optim import DecentralizedOptimizer
 from repro.core.topology import Topology
+from repro.telemetry import trace
 from repro.telemetry.trace import host_span, step_span
 
 PyTree = Any
 _END = object()   # batch_iter ran dry
+_consensus_distance = jax.jit(gossip.consensus_distance)
 
 
 @jax.tree_util.register_dataclass
@@ -170,6 +173,7 @@ class DecentralizedTrainer:
                 node_axis=self.node_axis if self.mesh is not None else None)
         self._validate_scenario(kind)
         self._validate_overlap()
+        self._record = True       # see recording()
         self._comm_gamma = None   # resolved on first sight of params
         self._comm_bits = None    # wire bits per site per node per step
         # the execution backend owns compilation (LAZY, with buffer
@@ -271,6 +275,22 @@ class DecentralizedTrainer:
         return self._runtime.init_state(build, params, mstate)
 
     # -- one jitted decentralized step ---------------------------------------
+    @contextlib.contextmanager
+    def recording(self, record):
+        """Whether the steps dispatched within the block have their
+        metrics read: a bool for :meth:`step`, a chunk's k bools for
+        :meth:`step_chunk`.  Where it is false the same compiled step skips
+        the ``consensus`` metric (NaN), whose all-reduce of every parameter
+        is the costly one across chips (DESIGN.md §9).  Outside any block
+        every step records.  The loops state it around each dispatch, so
+        the ``step`` call, and whatever stands in for it, stays as it
+        was."""
+        prev, self._record = self._record, record
+        try:
+            yield
+        finally:
+            self._record = prev
+
     def step(self, state: TrainState, batch: PyTree, rng,
              collect: bool = False):
         """One decentralized step on the selected execution backend.
@@ -278,9 +298,11 @@ class DecentralizedTrainer:
         first to keep a state across repeated runs).  ``collect=True``
         selects the telemetry-collecting trace (DESIGN.md §10) — a
         separately compiled variant of the same step, so ``False`` (the
-        default) stays the exact pre-telemetry graph."""
+        default) stays the exact pre-telemetry graph.  Its metrics hold
+        ``consensus`` unless a :meth:`recording` block says otherwise."""
         self._comm_setup(state.params)
-        return self._runtime.step(state, batch, rng, collect=collect)
+        return self._runtime.step(state, batch, rng, record=self._record,
+                                  collect=collect)
 
     # -- k fused steps under one dispatch (lax.scan over the chunk) -----------
     def step_chunk(self, state: TrainState, batches: PyTree, rng,
@@ -294,9 +316,14 @@ class DecentralizedTrainer:
         Returns the final state, the advanced rng, and metrics stacked [k].
         ``collect=True`` selects the telemetry-collecting chunk trace (every
         step of the chunk collects; the recorder keeps on-cadence rows).
+        Which steps compute ``consensus`` follows :meth:`recording`.
         """
         self._comm_setup(state.params)
-        return self._runtime.step_chunk(state, batches, rng, collect=collect)
+        record = self._record
+        if isinstance(record, bool):
+            record = (record,) * jax.tree.leaves(batches)[0].shape[0]
+        return self._runtime.step_chunk(state, batches, rng, record,
+                                        collect=collect)
 
     # -- host-side batch placement / probes ------------------------------------
     def put_batch(self, batch: PyTree, lead: int = 0):
@@ -323,21 +350,28 @@ class DecentralizedTrainer:
         return self._runtime.evaluate(state, eval_fn, batches)
 
 
+def _recorded(i, steps, log_every) -> bool:
+    """Whether the loops record step ``i`` of ``steps``: on ``log_every``
+    boundaries and at the final step.  The loops tell the step the same, so
+    only these steps compute the ``consensus`` metric; each is counted as
+    ``tm/metrics/consensus``."""
+    return bool(log_every and i % log_every == 0) or i == steps - 1
+
+
 def _record_step(history, i, steps, log_every, log_fn, get_metrics):
     """THE logging cadence, shared by both loops (the scanned loop's
     step-identical-history contract depends on it): print+append on log_every
     boundaries and the final step, append silently on the final step
-    otherwise.  ``get_metrics() -> {name: float}`` is called lazily so the
-    scanned loop only pulls a chunk's metrics off-device when some step in
-    it is actually recorded.  That pull is the loop's only sync with the
-    device, marked ``tm/host/fetch``."""
-    logged = log_every and (i % log_every == 0 or i == steps - 1)
-    if not (logged or i == steps - 1):
+    otherwise (:func:`_recorded`).  ``get_metrics() -> {name: float}`` is
+    called lazily so the scanned loop only pulls a chunk's metrics
+    off-device when some step in it is actually recorded.  That pull is the
+    loop's only sync with the device, marked ``tm/host/fetch``."""
+    if not _recorded(i, steps, log_every):
         return
     with host_span("tm/host/fetch"):
         m = get_metrics()
     history.append({"step": i, **m})
-    if logged:
+    if log_every:
         log_fn(f"step {i:5d}  " + "  ".join(
             f"{k}={v:.4f}" for k, v in m.items()))
 
@@ -357,7 +391,10 @@ def run_training(trainer: DecentralizedTrainer, state: TrainState,
     AFTER the step's split, so a run restarted from ``(state, rng)``
     continues the exact same stream (the save->resume parity pinned in
     tests/test_runtime.py).  ``step_offset`` makes a resumed run log/record
-    absolute step indices with the uninterrupted run's cadence.
+    absolute step indices with the uninterrupted run's cadence.  Each step
+    is told whether it is recorded (:func:`_recorded`, through
+    ``trainer.recording``); only recorded steps compute the ``consensus``
+    metric.
 
     ``telemetry`` is an optional duck-typed recorder (see
     ``repro.telemetry.TelemetryRecorder``): on-cadence steps
@@ -395,8 +432,12 @@ def run_training(trainer: DecentralizedTrainer, state: TrainState,
                 collect = telemetry is not None and telemetry.wants(i)
                 probe = (trainer.probe_metrics(state, batch, sub)
                          if collect else {})
-                state, metrics = trainer.step(state, batch, sub,
-                                              collect=collect)
+                record = _recorded(i, total, log_every)
+                if record:
+                    trace.count("tm/metrics/consensus")
+                with trainer.recording(record):
+                    state, metrics = trainer.step(state, batch, sub,
+                                                  collect=collect)
             if telemetry is not None:
                 metrics = telemetry.consume(i, {**metrics, **probe})
             _record_step(history, i, total, log_every, log_fn,
@@ -480,8 +521,13 @@ def run_training_scanned(trainer: DecentralizedTrainer, state: TrainState,
                 probe = (trainer.probe_metrics(state, stacked, rng,
                                                chunked=True)
                          if collect else {})
-                state, rng, metrics = trainer.step_chunk(
-                    state, stacked, rng, collect=collect)
+                record = tuple(
+                    _recorded(step_offset + done + j, step_offset + total,
+                              log_every) for j in range(k))
+                trace.count("tm/metrics/consensus", sum(record))
+                with trainer.recording(record):
+                    state, rng, metrics = trainer.step_chunk(
+                        state, stacked, rng, collect=collect)
             if telemetry is not None:
                 # host probe scalars broadcast [k] so the chunk consumer's
                 # per-step indexing sees them on every row
@@ -503,6 +549,7 @@ def run_training_scanned(trainer: DecentralizedTrainer, state: TrainState,
                              step_offset + total, log_every, log_fn,
                              lambda j=j: chunk_metrics(j))
             last_metrics = lambda k=k, cm=chunk_metrics: cm(k - 1)
+            last_recorded = record[-1]
             abs_done = step_offset + done
             if checkpoint_fn and checkpoint_every and (
                     (abs_done + k) // checkpoint_every
@@ -517,6 +564,9 @@ def run_training_scanned(trainer: DecentralizedTrainer, state: TrainState,
         if last_metrics is not None and (
                 not history
                 or history[-1]["step"] != step_offset + done - 1):
-            history.append({"step": step_offset + done - 1,
-                            **last_metrics()})
+            row = last_metrics()
+            if not last_recorded:
+                # that step skipped the metric; the state is its result
+                row["consensus"] = float(_consensus_distance(state.params))
+            history.append({"step": step_offset + done - 1, **row})
     return state, history

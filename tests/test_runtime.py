@@ -460,3 +460,116 @@ def test_launch_steps_sharded_builder_matches_vmap():
     res = _run_sub(_STEPS_SHARDED_SCRIPT)
     assert "STEPS_SHARDED_OK" in res.stdout, \
         res.stdout[-1500:] + res.stderr[-3000:]
+
+
+_RECORD_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import re
+import numpy as np
+import jax, jax.numpy as jnp
+from repro.core import gossip, optim, topology
+from repro.launch.mesh import make_debug_mesh
+from repro.runtime import ShardedRuntime
+from repro.train import DecentralizedTrainer, run_training, \
+    run_training_scanned
+
+
+def init_fn(key):
+    return ({"w": jax.random.normal(key, (6, 5)) * 0.3,
+             "b": jnp.zeros(5)}, {})
+
+
+def loss_fn(p, ms, batch, rng):
+    xb, yb = batch
+    logits = xb @ p["w"] + p["b"]
+    ce = jnp.mean(jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
+        logits, yb[:, None].astype(jnp.int32), -1)[:, 0])
+    return ce, ({}, {})
+
+
+rng = np.random.default_rng(0)
+bs = [(rng.normal(size=(4, 4, 6)).astype(np.float32),
+       rng.integers(0, 5, size=(4, 4))) for _ in range(10)]
+mesh = make_debug_mesh(shape=(4,), axes=("data",))
+tr = DecentralizedTrainer(
+    loss_fn, optim.make_optimizer("qg_dsgdm_n", lr=0.1), topology.ring(4),
+    mesh=mesh, node_axis="data")
+assert isinstance(tr._runtime, ShardedRuntime)
+fresh = lambda: tr.init(jax.random.PRNGKey(0), init_fn)
+silent = lambda *_: None
+
+# the compiled step: every all-reduce of more than a scalar (the consensus
+# metric's, over every parameter) sits inside the conditional's branch
+rt = tr._runtime
+hlo = rt._build_step().lower(
+    fresh(), jax.tree.map(jnp.asarray, bs[0]), jax.random.PRNGKey(1),
+    rt._record_arg(True)).compile().as_text()
+wide = []
+for line in hlo.splitlines():
+    m = re.search(r"= (\(.*?\)|\S+) all-reduce(-start)?\(", line)
+    if m and re.search(r"\[\d", m.group(1)):
+        wide.append(re.search(r'op_name="([^"]*)"', line).group(1))
+assert wide and all("/cond/" in name for name in wide), wide
+print("HLO_OK", wide)
+
+# the reference: a direct caller's step records, and its consensus is the
+# distance of the state it returns
+st, key, ref = fresh(), jax.random.PRNGKey(1), []
+for b in bs:
+    key, sub = jax.random.split(key)
+    st, m = tr.step(st, jax.tree.map(jnp.asarray, b), sub)
+    want = float(gossip.consensus_distance(st.params))
+    np.testing.assert_allclose(float(m["consensus"]), want, rtol=1e-6)
+    ref.append({k: float(v) for k, v in m.items()})
+
+_, h = run_training(tr, fresh(), iter(bs), 7, rng=jax.random.PRNGKey(1),
+                    log_every=3, log_fn=silent)
+assert [r["step"] for r in h] == [0, 3, 6], h
+for r in h:
+    np.testing.assert_allclose(r["consensus"], ref[r["step"]]["consensus"],
+                               rtol=1e-6)
+_, hs = run_training_scanned(tr, fresh(), iter(bs), 7, chunk=4,
+                             rng=jax.random.PRNGKey(1), log_every=3,
+                             log_fn=silent)
+assert [r["step"] for r in hs] == [0, 3, 6], hs
+for a, b in zip(h, hs):
+    for k in a:
+        np.testing.assert_allclose(a[k], b[k], rtol=1e-6, err_msg=k)
+print("RECORDED_OK")
+
+# inside a recording(False) block the same step leaves the metric out
+with tr.recording(False):
+    _, m = tr.step(fresh(), jax.tree.map(jnp.asarray, bs[0]),
+                   jax.random.PRNGKey(1))
+assert np.isnan(float(m["consensus"])) and np.isfinite(float(m["loss"]))
+assert tr._record is True
+
+# the iterator runs dry at a chunk boundary (8 batches, the last chunk
+# unrecorded) and inside a chunk (6): the last row's consensus is finite
+# and that step's own
+for n_batches in (8, 6):
+    _, he = run_training_scanned(tr, fresh(), iter(bs[:n_batches]), 10,
+                                 chunk=4, rng=jax.random.PRNGKey(1),
+                                 log_fn=silent)
+    last = he[-1]
+    assert last["step"] == n_batches - 1, he
+    assert np.isfinite(last["consensus"]), he
+    np.testing.assert_allclose(last["consensus"],
+                               ref[n_batches - 1]["consensus"], rtol=1e-6)
+    assert all(np.isfinite(r["consensus"]) for r in he), he
+print("EXHAUSTED_OK")
+print("RECORD_OK")
+"""
+
+
+def test_consensus_only_on_recorded_steps():
+    """The consensus metric is computed only on the steps the loops record
+    (sharded runtime, ``qg_dsgdm_n`` on ring-4, 4 forced host devices): its
+    all-reduce of every parameter compiles inside a conditional branch; a
+    recorded step's value is the distance of the state it returns, in the
+    per-step loop and the scanned loop alike; and a run whose iterator runs
+    dry still ends on a finite value."""
+    res = _run_sub(_RECORD_SCRIPT)
+    assert "RECORD_OK" in res.stdout, \
+        res.stdout[-1500:] + res.stderr[-3000:]

@@ -195,3 +195,19 @@ def test_registry_times_spans():
     assert spans["tm/host/x"]["count"] == 2 and spans["tm/host/x"]["s"] >= 0
     trace.reset()
     assert trace.totals()["spans"] == {} and reg.spans == {}
+
+
+@pytest.mark.parametrize("scanned", [False, True])
+@pytest.mark.parametrize("log_every, recorded", [(0, 1), (2, 3)])
+def test_consensus_counter_counts_recorded_steps(scanned, log_every,
+                                                 recorded):
+    """``tm/metrics/consensus`` counts the steps dispatched with ``record``
+    true: the final step alone without logging, else each ``log_every``
+    boundary and the final step.  Every recorded row has its consensus."""
+    tr, st, ds = _job()
+    trace.enable()
+    _, hist = _loop(scanned, tr, st, iter(ds.next_batch, None), 5,
+                    log_every=log_every)
+    assert trace.totals()["counters"]["tm/metrics/consensus"] == recorded
+    assert len(hist) == recorded
+    assert all(np.isfinite(r["consensus"]) for r in hist)
