@@ -90,15 +90,14 @@ def _xla_bytes(opt, params, w):
     """XLA's 'bytes accessed' for one compiled optimizer step
     (informational — includes the gossip exchange and CPU-side fusion)."""
     try:
-        from repro.launch.roofline import cost_analysis_dict
-
         def step(p, g, s):
             return opt.step(p, g, s, w=w, lr=0.1, t=0)
 
         grads = jax.tree.map(jnp.zeros_like, params)
         compiled = jax.jit(step).lower(params, grads,
                                        opt.init(params)).compile()
-        return float(cost_analysis_dict(compiled).get("bytes accessed", 0.0))
+        return float((compiled.cost_analysis() or {}).get("bytes accessed",
+                                                          0.0))
     except Exception:
         return 0.0
 

@@ -33,7 +33,6 @@ runs; ``--only <name>`` selects a single table.
             checked bit-identical before timing; the CI gate holds
             engine tokens/s >= 1.5x sequential at n_slots=8)
   kernels   Pallas kernel microbench vs jnp reference
-  roofline  aggregate the dry-run artifacts into the §Roofline table
 
 ``--json <path>`` additionally writes every row to a machine-readable JSON
 list (``BENCH_*.json`` convention) for trajectory tracking.  Every exported
@@ -44,7 +43,6 @@ row is stamped with ``schema_version``, ``timestamp`` (caller-supplied via
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import subprocess
@@ -305,8 +303,8 @@ def telemetry(quick=False):
 
 
 def serving(quick=False):
-    """Batched-decode throughput on a reduced arch (CPU; the decode_32k
-    dry-run bounds the TPU-side numbers)."""
+    """Batched-decode throughput on a reduced arch (CPU timings, never a
+    device metric)."""
     import jax
     import jax.numpy as jnp
     from repro.configs import get_config
@@ -453,36 +451,12 @@ def kernels(quick=False):
     csv_row("kernels/ssd_scan_pallas_interp", us_k, f"jnp_ref_us={us_r:.1f}")
 
 
-def roofline(quick=False):
-    """Aggregate dry-run JSON artifacts into §Roofline CSV rows."""
-    pat = os.path.join("experiments", "dryrun", "*.json")
-    rows = sorted(glob.glob(pat))
-    if not rows:
-        print("# no dry-run artifacts found — run: "
-              "PYTHONPATH=src python -m repro.launch.dryrun")
-        return
-    for path in rows:
-        rec = json.load(open(path))
-        rt = rec.get("roofline")
-        if not rt:
-            continue
-        name = os.path.basename(path).replace(".json", "")
-        lower = rt["step_s_lower_bound"] * 1e6
-        csv_row(
-            f"roofline/{name}", lower,
-            f"bottleneck={rt['bottleneck']},compute_s={rt['compute_s']:.4f},"
-            f"memory_s={rt['memory_s']:.4f},"
-            f"collective_s={rt['collective_s']:.4f},"
-            f"useful_flops={rec.get('useful_flops_ratio', 0):.3f}",
-            platform=rec.get("platform", "unknown"))
-
-
 TABLES = {
     "table1": table1, "table2": table2, "table4": table4, "table5": table5,
     "table6": table6, "fig3": fig3, "fig6": fig6, "comm": comm,
     "topology": topology, "loop": loop, "telemetry": telemetry,
     "runtime": runtime, "scenario": scenario, "serving": serving,
-    "serve": serve, "kernels": kernels, "roofline": roofline,
+    "serve": serve, "kernels": kernels,
 }
 
 

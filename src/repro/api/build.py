@@ -205,12 +205,8 @@ def wire_stats(trainer: DecentralizedTrainer, params) -> dict:
     }
     resolved = trainer._resolved
     messages = None
-    if resolved.kind in ("ring", "sparse"):
-        schedule = resolved.schedule
-        if schedule is None:   # 'ring' special case: same compiled rounds
-            from repro.core.gossip import compile_gossip_schedule
-            schedule = compile_gossip_schedule(trainer.topology)
-        messages = schedule.messages_per_step()
+    if resolved.kind == "sparse":
+        messages = resolved.schedule.messages_per_step()
         out["messages_per_step"] = messages
     if trainer.comm is not None:
         comp_bits = trainer.comm.wire_bits_per_site(params) * sites

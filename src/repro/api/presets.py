@@ -199,6 +199,5 @@ def _lm100m():
             "arch": "tinyllama-1.1b",
             "overrides": {"name": "llama-100m", "n_layers": 8,
                           "d_model": 768, "n_heads": 12, "n_kv_heads": 4,
-                          "head_dim": 64, "d_ff": 2048, "vocab_size": 8192,
-                          "mesh_divisor": 1},
+                          "head_dim": 64, "d_ff": 2048, "vocab_size": 8192},
             "chunk": 128}))

@@ -14,8 +14,8 @@ JSON-plain values.  ``apply_overrides(spec, ["loop.steps=3", ...])``
 implements ``--set``-style dotted overrides on top of any spec or preset.
 
 Validation is EAGER and cross-field: ``spec.validate()`` (called by
-``build``) surfaces topology x n mismatches, ``ring_ppermute`` on a
-non-ring, unsatisfiable ``min_per_client``, malformed compressor specs,
+``build``) surfaces topology x n mismatches, unknown gossip schedules,
+unsatisfiable ``min_per_client``, malformed compressor specs,
 unknown optimizer/model names — at spec time, with actionable messages,
 instead of deep inside a jitted step builder.
 """
@@ -99,7 +99,7 @@ class GossipSpec:
     """Collective schedule for the mix (DESIGN.md §7).  The mesh itself is a
     runtime object and is passed to ``build(spec, mesh=...)``."""
 
-    schedule: str = "auto"            # auto | dense | ring_ppermute | sparse_ppermute
+    schedule: str = "auto"            # auto | dense | sparse_ppermute
     node_axis: str = "data"
 
 
@@ -336,10 +336,6 @@ class ExperimentSpec:
             err("gossip.schedule", f"unknown schedule "
                 f"{self.gossip.schedule!r}; valid: "
                 f"{' | '.join(GOSSIP_SCHEDULES)}")
-        if self.gossip.schedule == "ring_ppermute" and topo.name != "ring":
-            err("gossip.schedule",
-                "ring_ppermute mixes with a ring schedule only; use "
-                f"'sparse_ppermute' for topology={topo.name!r}")
         # data
         d = self.data
         if d.dataset not in ("classification", "lm_domains"):

@@ -1,7 +1,7 @@
 """Architecture registry: ``--arch <id>`` resolves here."""
 from __future__ import annotations
 
-from .base import INPUT_SHAPES, InputShape, ModelConfig
+from .base import ModelConfig
 
 from . import (
     arctic_480b,
@@ -37,11 +37,4 @@ def get_config(arch: str, *, reduced: bool = False) -> ModelConfig:
     return cfg.reduced() if reduced else cfg
 
 
-def get_shape(name: str) -> InputShape:
-    if name not in INPUT_SHAPES:
-        raise ValueError(f"unknown shape {name!r}; have {sorted(INPUT_SHAPES)}")
-    return INPUT_SHAPES[name]
-
-
-__all__ = ["ARCHS", "INPUT_SHAPES", "ModelConfig", "InputShape",
-           "get_config", "get_shape"]
+__all__ = ["ARCHS", "ModelConfig", "get_config"]

@@ -17,5 +17,4 @@ def config() -> ModelConfig:
         period=("moe",),
         moe=MoEConfig(n_experts=128, top_k=2, dense_ff=4864),
         source="hf:Snowflake/snowflake-arctic-base",
-        supports_long_context=False,
     )

@@ -57,9 +57,6 @@ class ModelConfig:
     audio_frontend_stub: bool = False
     # citation for the config (paper / model card)
     source: str = ""
-    # serving: does this arch support the 500k decode shape?
-    supports_long_context: bool = False
-    mesh_divisor: int = 16            # model-axis size the dims must divide by
 
     @property
     def resolved_head_dim(self) -> int:
@@ -84,8 +81,7 @@ class ModelConfig:
         return self.period * self.n_periods + (self.period[0],) * self.tail_layers
 
     def n_params(self) -> int:
-        """Analytic parameter count (embedding + blocks), used for roofline
-        MODEL_FLOPS = 6*N*D."""
+        """Analytic parameter count (embedding + blocks)."""
         d, f, hd = self.d_model, self.d_ff, self.resolved_head_dim
         n_attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + \
             self.n_heads * hd * d
@@ -159,21 +155,5 @@ class ModelConfig:
             ssm=ssm,
             shared_attn_every=2 if self.shared_attn_every else 0,
             n_image_tokens=16 if self.n_image_tokens else 0,
-            mesh_divisor=1,
         )
 
-
-@dataclasses.dataclass(frozen=True)
-class InputShape:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # 'train' | 'prefill' | 'decode'
-
-
-INPUT_SHAPES: dict[str, InputShape] = {
-    "train_4k": InputShape("train_4k", 4_096, 256, "train"),
-    "prefill_32k": InputShape("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": InputShape("decode_32k", 32_768, 128, "decode"),
-    "long_500k": InputShape("long_500k", 524_288, 1, "decode"),
-}

@@ -15,5 +15,4 @@ def config() -> ModelConfig:
         period=("dense",),
         rope_theta=8_000_000.0,
         source="hf:CohereForAI/c4ai-command-r-v01",
-        supports_long_context=False,  # full attention only -> skip long_500k
     )

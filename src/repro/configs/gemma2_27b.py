@@ -20,8 +20,4 @@ def config() -> ModelConfig:
         logit_softcap=30.0,
         rope_theta=10000.0,
         source="arXiv:2408.00118",
-        # sliding-window *serving variant* makes 500k decode feasible:
-        # local layers window the cache; the alternating global layers run in
-        # windowed mode too for this shape (documented in DESIGN.md).
-        supports_long_context=True,
     )

@@ -17,5 +17,4 @@ def config() -> ModelConfig:
         period=("moe",),
         moe=MoEConfig(n_experts=40, top_k=8),
         source="hf:ibm-granite/granite-3.0-1b-a400m-base",
-        supports_long_context=False,
     )

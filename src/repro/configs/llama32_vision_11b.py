@@ -18,5 +18,4 @@ def config() -> ModelConfig:
         rope_theta=500_000.0,
         n_image_tokens=1601,   # 1 tile x (40x40 patches + cls)
         source="hf:meta-llama/Llama-3.2-11B-Vision",
-        supports_long_context=False,
     )

@@ -12,10 +12,9 @@ def config() -> ModelConfig:
         n_heads=0,          # attention-free
         n_kv_heads=0,
         d_ff=0,
-        vocab_size=50280,   # padded to 50432 for the 16-way model axis
+        vocab_size=50280,   # stored padded to 50432 (a multiple of 256)
         head_dim=64,
         period=("mamba",),
         ssm=SSMConfig(d_state=128, d_conv=4, expand=2, head_dim=64),
         source="arXiv:2405.21060",
-        supports_long_context=True,  # O(1) state decode
     )

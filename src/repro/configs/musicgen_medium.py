@@ -17,5 +17,4 @@ def config() -> ModelConfig:
         period=("dense",),
         audio_frontend_stub=True,
         source="arXiv:2306.05284",
-        supports_long_context=False,
     )

@@ -16,5 +16,4 @@ def config() -> ModelConfig:
         period=("dense",),
         rope_theta=1_000_000.0,
         source="arXiv:2407.10671",
-        supports_long_context=False,
     )

@@ -14,5 +14,4 @@ def config() -> ModelConfig:
         vocab_size=32000,
         period=("dense",),
         source="arXiv:2401.02385",
-        supports_long_context=False,
     )

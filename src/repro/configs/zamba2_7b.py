@@ -18,8 +18,7 @@ def config() -> ModelConfig:
         head_dim=112,
         period=("mamba",) * 6,
         shared_attn_every=6,
-        window=4096,     # shared-attn KV is windowed -> 500k decode feasible
+        window=4096,     # the shared attention's KV is windowed
         ssm=SSMConfig(d_state=64, d_conv=4, expand=2, head_dim=64),
         source="arXiv:2411.15242",
-        supports_long_context=True,
     )

@@ -3,15 +3,14 @@
 Layout convention (see DESIGN.md §3): every parameter / optimizer-state leaf
 carries the decentralized node index as its *leading* axis, shape
 ``[n_nodes, ...]``.  On CPU that axis lives in memory; on a TPU mesh it is
-sharded over the ``data`` (or ``pod``) mesh axis, so the mixing contraction
+sharded over the ``data`` mesh axis, so the mixing contraction
 below becomes collectives over that axis.
 
 Schedules (DESIGN.md §7):
 
 * ``mix_dense``  — paper-faithful: ``x <- einsum('nm,m...->n...', W, x)``.
   For a sharded node axis XLA lowers this to an all-gather (every node reads
-  every other node's model) even when W is sparse.  This is the *baseline*
-  collective schedule recorded in EXPERIMENTS.md §Perf.
+  every other node's model) even when W is sparse.
 * ``mix_sparse_shardmap`` — the topology compiler's schedule: ANY
   doubly-stochastic ``W`` (including each phase of a time-varying stack) is
   decomposed once at setup time (``compile_gossip_schedule``) into weighted
@@ -19,12 +18,10 @@ Schedules (DESIGN.md §7):
   graphs, greedy edge-coloring for undirected graphs (social32, torus,
   star) — so bytes-on-wire scale with node degree, not n.  Phases whose
   decomposition would exceed the all-gather cost fall back to a dense
-  all-gather round automatically.
-* ``mix_ring_shardmap`` — the original ring-only special case (two
-  ppermutes), kept for the hillclimb/dry-run surface; the compiler produces
-  the identical schedule for ``ring(n)``.
+  all-gather round automatically.  A ring compiles to two rounds, one
+  ppermute to each neighbour.
 
-All of them act on whole pytrees, compute the same weighted sum (tested
+Both act on whole pytrees, compute the same weighted sum (tested
 against each other), and are differentiable (gossip happens outside the
 gradient in DSGD-family algorithms, but consensus experiments use it inside
 jitted loops).
@@ -49,12 +46,10 @@ PyTree = Any
 __all__ = [
     "mix_dense",
     "mix_leaf_dense",
-    "mix_ring_shardmap",
     "mix_sparse_shardmap",
     "make_sparse_mix_fn",
     "apply_schedule_local",
     "make_local_mix_fn",
-    "neighbor_sum_ppermute",
     "GossipSchedule",
     "PhaseSchedule",
     "ResolvedGossip",
@@ -100,67 +95,6 @@ def mix_dense(w: jax.Array | np.ndarray, tree: PyTree) -> PyTree:
     """Dense mixing of a node-stacked pytree: leaf[n,...] <- sum_m W[n,m] leaf[m,...]."""
     w = jnp.asarray(w)
     return jax.tree.map(functools.partial(mix_leaf_dense, w), tree)
-
-
-def neighbor_sum_ppermute(
-    x: jax.Array,
-    *,
-    axis_name: str,
-    n: int,
-    self_weight: float,
-    side_weight: float,
-) -> jax.Array:
-    """Ring mixing of a *sharded* (per-node local) array inside shard_map.
-
-    ``x`` here is the local shard (no node axis); neighbours are reached with
-    two collective-permutes around the ring defined by ``axis_name``.  ``n``
-    is the static ring size (``mesh.shape[axis_name]``): the permutation
-    lists need a concrete size.
-    """
-    if n == 1:
-        return x
-    fwd = [(i, (i + 1) % n) for i in range(n)]
-    bwd = [(i, (i - 1) % n) for i in range(n)]
-    from_left = jax.lax.ppermute(x, axis_name, perm=fwd)   # value of node i-1
-    from_right = jax.lax.ppermute(x, axis_name, perm=bwd)  # value of node i+1
-    if n == 2:
-        # left and right neighbour coincide; weights collapse to 1/2, 1/2.
-        return (x + from_left) * 0.5
-    return self_weight * x + side_weight * (from_left + from_right)
-
-
-def mix_ring_shardmap(
-    tree: PyTree,
-    *,
-    mesh: jax.sharding.Mesh,
-    axis_name: str,
-    self_weight: float = 1.0 / 3.0,
-) -> PyTree:
-    """Ring gossip over a pytree whose leaves have a leading node axis
-    sharded on ``axis_name``.  Equivalent to ``mix_dense(ring(n).w(), tree)``
-    but exchanges only the two ring neighbours (2/(n-1) of the all-gather
-    bytes).  Mesh axes other than the node axis stay under compiler control
-    (``auto``), so leaves may simultaneously be sharded over 'model'/'data'.
-    """
-    side = (1.0 - self_weight) / 2.0
-    n = dict(mesh.shape)[axis_name]
-
-    def local_fn(local_tree):
-        return jax.tree.map(
-            lambda x: neighbor_sum_ppermute(
-                x, axis_name=axis_name, n=n, self_weight=self_weight,
-                side_weight=side),
-            local_tree,
-        )
-
-    specs = jax.tree.map(
-        lambda x: P(axis_name, *([None] * (x.ndim - 1))), tree
-    )
-    # manual only over the node axis; 'model'/'data' stay compiler-managed
-    return _shard_map(
-        local_fn, mesh=mesh, in_specs=(specs,), out_specs=specs,
-        manual_axes=frozenset({axis_name}),
-    )(tree)
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs, manual_axes):
@@ -492,7 +426,7 @@ def make_sparse_mix_fn(schedule: GossipSchedule, *, mesh, axis_name: str,
     return mix_fn
 
 
-GOSSIP_SCHEDULES = ("auto", "dense", "ring_ppermute", "sparse_ppermute")
+GOSSIP_SCHEDULES = ("auto", "dense", "sparse_ppermute")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -500,12 +434,10 @@ class ResolvedGossip:
     """Outcome of ``resolve_gossip``: which mix implementation to install
     behind the zoo-wide ``mix_fn`` hook.
 
-    ``kind`` is ``'dense'`` (keep the optimizer's dense contraction),
-    ``'ring'`` (two-ppermute ring special case) or ``'sparse'`` (compiled
-    schedule; ``schedule`` holds the :class:`GossipSchedule`).  ``mix_fn``
-    materializes the hook closure — callers that mix with a traced step
-    counter (the trainer) pass ``t`` per step; static builders use the
-    default phase 0.
+    ``kind`` is ``'dense'`` (keep the optimizer's dense contraction) or
+    ``'sparse'`` (compiled schedule; ``schedule`` holds the
+    :class:`GossipSchedule`).  ``mix_fn`` materializes the hook closure for
+    the step's traced counter ``t``.
     """
 
     kind: str
@@ -518,25 +450,20 @@ class ResolvedGossip:
         optimizer's dense default should stand."""
         if self.kind == "dense":
             return None
-        if self.kind == "ring":
-            return lambda w, tree: mix_ring_shardmap(
-                tree, mesh=self.mesh, axis_name=self.node_axis)
         return make_sparse_mix_fn(self.schedule, mesh=self.mesh,
                                   axis_name=self.node_axis, w_ref=w_ref, t=t)
 
 
 def resolve_gossip(topo: Topology, *, schedule: str = "auto", mesh=None,
                    node_axis: str | None = None) -> ResolvedGossip:
-    """THE gossip-schedule selection rules, shared by every assembly path
-    (``DecentralizedTrainer`` and ``launch/steps.build_train_step``
-    previously each hand-rolled a diverging copy).
+    """The gossip-schedule selection rules of ``DecentralizedTrainer`` on
+    its one-node-per-device runtimes (``vmap`` and ``sharded``).
 
     * ``'dense'`` — always the dense contraction (also the n=1 reduction).
     * ``'auto'``  — dense without a mesh; the compiled sparse schedule when
-      a mesh carries the node axis (the trainer's historical behavior).
-    * ``'ring_ppermute'`` / ``'sparse_ppermute'`` — explicit; require a mesh
-      whose ``node_axis`` has size ``topo.n``, and ring_ppermute requires an
-      actual ring topology.
+      a mesh carries the node axis.
+    * ``'sparse_ppermute'`` — explicit; requires a mesh whose ``node_axis``
+      has size ``topo.n``.
 
     All invalid combinations raise here, at resolve time, with actionable
     messages — not from deep inside a jitted step builder.
@@ -559,13 +486,6 @@ def resolve_gossip(topo: Topology, *, schedule: str = "auto", mesh=None,
         raise ValueError(
             f"mesh axis {node_axis!r} has size {axes[node_axis]}, topology "
             f"has n={topo.n}")
-    if schedule == "ring_ppermute":
-        if topo.name != "ring":
-            raise ValueError(
-                "ring_ppermute mixes with a ring schedule only; use "
-                f"gossip_schedule='sparse_ppermute' for topology="
-                f"{topo.name!r}")
-        return ResolvedGossip("ring", None, mesh, node_axis)
     return ResolvedGossip("sparse", compile_gossip_schedule(topo), mesh,
                           node_axis)
 

@@ -1,2 +1,2 @@
-"""Launchers: mesh construction, multi-pod dry-run, roofline analysis,
-training and serving CLIs."""
+"""Launchers: mesh construction, compile cache, distributed init, training
+and serving CLIs."""

@@ -1,9 +1,8 @@
-"""Production mesh construction (FUNCTION, never touches jax device state at
-import time).
-
-Target hardware: TPU v5e, 256 chips/pod (16x16), 2 pods = 512 chips.
-On this CPU container the dry-run forces 512 host platform devices before any
-jax import (see launch/dryrun.py lines 1-2).
+"""Mesh construction (functions; nothing touches jax device state at import
+time).  The meshes are small: one node axis over the chips of one host (1 or
+4 TPU v5e chips), or forced host devices in CPU tests and examples, which set
+``XLA_FLAGS=--xla_force_host_platform_device_count=<count>`` before any jax
+import.
 
 Multi-process correctness (DESIGN.md §12): under ``jax.distributed`` every
 process sees the GLOBAL device list, but a mesh that shards host-fed data
@@ -23,8 +22,8 @@ import numpy as np
 
 def _device_grid(n: int, what: str):
     """The first ``n`` global devices in PROCESS-MAJOR order, as a flat
-    numpy object array — the canonical device layout both mesh builders
-    reshape.  Raises actionable errors when the process/device arithmetic
+    numpy object array — the canonical device layout the mesh builder
+    reshapes.  Raises actionable errors when the process/device arithmetic
     cannot work."""
     import jax
 
@@ -32,7 +31,7 @@ def _device_grid(n: int, what: str):
     devices = jax.devices()
     if len(devices) < n:
         hint = (
-            "the dry-run entry point must set "
+            "the entry point must set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=<count> "
             "before any jax import" if procs == 1 else
             "each process must be started with jax.distributed.initialize("
@@ -63,16 +62,6 @@ def _device_grid(n: int, what: str):
                 "initialize)")
         grid.extend(local[:per])
     return np.array(grid)
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    import jax
-
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = math.prod(shape)
-    grid = _device_grid(n, f"mesh {shape}")
-    return jax.sharding.Mesh(grid.reshape(shape), axes)
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):

@@ -1,79 +1,20 @@
-"""Render the §Dry-run / §Roofline markdown tables from dryrun artifacts.
+"""Render the §Kernels / §Serve markdown tables from the CPU benchmark
+JSON that ``benchmarks.run --json`` writes.
 
-    PYTHONPATH=src python -m repro.launch.report [--dir experiments/dryrun]
-                                                 [--out report.md]
+    PYTHONPATH=src python -m repro.launch.report --what kernels \
+        --bench-kernels BENCH_kernels.json [--out report.md]
 
 Table/formatting helpers live in ``repro.telemetry.report`` (the shared
-markdown machinery — DESIGN.md §10); this module is the dryrun-artifact
-front end.  Records are partial by design: a dryrun that failed before the
-roofline or memory analysis still produces a JSON artifact, so every lookup
-here tolerates missing optional keys (``roofline``, ``memory_analysis``,
-``n_chips``, ...) instead of raising.
+markdown machinery — DESIGN.md §10).  An absent or unreadable file renders
+a placeholder line instead of raising.
 """
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 
-from repro.telemetry.report import fmt_s, markdown_table
-
-
-def load(dir_: str) -> list[dict]:
-    recs = []
-    for p in sorted(glob.glob(os.path.join(dir_, "*.json"))):
-        r = json.load(open(p))
-        r["_file"] = os.path.basename(p)
-        recs.append(r)
-    return recs
-
-
-def roofline_table(recs: list[dict], mesh: str = "single",
-                   gossip: str | None = None) -> str:
-    rows = []
-    for r in recs:
-        if r.get("mesh") != mesh or "roofline" not in r:
-            continue
-        if r.get("variant", "baseline") != "baseline":
-            continue
-        if gossip is not None and (r.get("gossip") or "dense") != gossip:
-            continue
-        if gossip is None and (r.get("gossip") or "dense") != "dense":
-            continue
-        rt = r["roofline"]
-        mem = str(r.get("memory_analysis", ""))
-        temp = ""
-        if "temp=" in mem:
-            temp = mem.split("temp=")[1].split(" ")[0]
-        rows.append([
-            r.get("arch", "?"), r.get("shape", "?"),
-            r.get("n_nodes", "-"),
-            fmt_s(rt.get("compute_s", 0.0)), fmt_s(rt.get("memory_s", 0.0)),
-            fmt_s(rt.get("collective_s", 0.0)),
-            f"**{rt.get('bottleneck', '?')}**",
-            f"{r.get('useful_flops_ratio', 0):.2f}", temp])
-    return markdown_table(
-        ["arch", "shape", "nodes", "compute", "memory", "collective",
-         "bottleneck", "useful FLOPs", "per-chip temp mem"], rows)
-
-
-def dryrun_table(recs: list[dict]) -> str:
-    rows = []
-    for r in recs:
-        if r.get("variant", "baseline") != "baseline" or \
-                (r.get("gossip") or "dense") != "dense":
-            continue
-        ok = "yes" if ("memory_analysis" in r and
-                       "failed" not in str(r["memory_analysis"])) else "?"
-        rows.append([
-            r.get("arch", "?"), r.get("shape", "?"), r.get("mesh", "?"),
-            r.get("n_chips", "-"),
-            f"{ok} ({r.get('full_compile_s', '-')}s)",
-            str(r.get("memory_analysis", ""))[:70]])
-    return markdown_table(
-        ["arch", "shape", "mesh", "chips", "compiled",
-         "memory analysis (per chip)"], rows)
+from repro.telemetry.report import markdown_table
 
 
 def serve_table(path: str) -> str:
@@ -159,12 +100,8 @@ def kernels_table(path: str) -> str:
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--dir", default="experiments/dryrun")
-    ap.add_argument("--what", default="roofline",
-                    choices=["roofline", "dryrun", "serve", "kernels",
-                             "both", "all"])
-    ap.add_argument("--mesh", default="single")
-    ap.add_argument("--gossip", default=None)
+    ap.add_argument("--what", default="kernels",
+                    choices=["serve", "kernels", "all"])
     ap.add_argument("--bench-serve", default="BENCH_serve.json",
                     metavar="PATH", help="serve bench JSON for --what "
                     "serve/all (absent file renders a placeholder)")
@@ -174,13 +111,7 @@ def main(argv=None):
     ap.add_argument("--out", default=None,
                     help="write the rendered markdown here instead of stdout")
     args = ap.parse_args(argv)
-    recs = load(args.dir)
     parts = []
-    if args.what in ("roofline", "both", "all"):
-        parts.append(roofline_table(recs, mesh=args.mesh,
-                                    gossip=args.gossip))
-    if args.what in ("dryrun", "both", "all"):
-        parts.append(dryrun_table(recs))
     if args.what in ("serve", "all"):
         parts.append(serve_table(args.bench_serve))
     if args.what in ("kernels", "all"):

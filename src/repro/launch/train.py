@@ -6,12 +6,9 @@ assembly path wires partition + topology + optimizer + comm + gossip
 schedule + loop from it.  Any spec field is reachable with
 ``--set section.key=value`` dotted overrides.
 
-Two modes:
-  * ``--reduced`` (default; CPU-runnable): trains the reduced variant of any
-    assigned architecture on synthetic non-i.i.d. LM data with the full
-    decentralized stack (node-stacked params, gossip topology, QG momentum).
-  * full-size: the same step functions the dry-run compiles, for real TPU
-    meshes (``--mesh single|multi``); on this container use dryrun.py.
+``--reduced`` (the default; CPU-runnable) trains the reduced variant of any
+assigned architecture on synthetic non-i.i.d. LM data with the full
+decentralized stack (node-stacked params, gossip topology, QG momentum).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \

@@ -5,16 +5,10 @@ The training/prefill path uses an *online-softmax chunked* implementation
 (`chunked_attention`) — a pure-jnp flash-attention: `lax.scan` over KV chunks
 so compiled peak memory is O(S * chunk) instead of O(S^2).  This is also the
 semantics the Pallas kernel (`repro.kernels.flash_attention`) implements; the
-model picks the kernel when ``use_pallas`` is set (TPU), jnp otherwise (CPU
-dry-run / tests).
-
-Sliding-window layers can skip KV chunks that are entirely outside the
-window (``skip_masked_chunks``) — a beyond-paper compute optimization
-measured in EXPERIMENTS.md §Perf.
+model picks the kernel when ``use_pallas`` is set, jnp otherwise.
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Any
 
 import jax
@@ -75,26 +69,12 @@ def chunked_attention(
     window: int = 0,         # 0 = full; else sliding window (causal only)
     softcap: float = 0.0,
     chunk: int = 1024,
-    skip_masked_chunks: bool = False,
-    unroll: bool = False,
-    remat_chunks: bool = False,
-    repeat_kv: bool = False,
 ) -> jax.Array:
     """Online-softmax attention scanning KV in chunks; GQA via head groups."""
     b, s, h, d = q.shape
     t = k.shape[1]
     kh = k.shape[2]
     assert h % kh == 0
-    if repeat_kv and kh != h:
-        # GQA score tensors [B,S,KH,G,C] split the head count over two dims
-        # (8x8 for 64 heads), which the SPMD partitioner can only shard
-        # 16-ways by 2D-splitting + collective-permuting the fp32 scores.
-        # Repeating KV to the full head count keeps ONE 16-divisible head dim
-        # (cheap: K/V are GQA-small; scores are the big tensor).
-        g_rep = h // kh
-        k = jnp.repeat(k, g_rep, axis=2)
-        v = jnp.repeat(v, g_rep, axis=2)
-        kh = h
     g = h // kh
     chunk = min(chunk, t)
     t_valid = t
@@ -143,73 +123,9 @@ def chunked_attention(
     vc_t = jnp.moveaxis(vc, 1, 0)
     idx = jnp.arange(n_chunks)
 
-    if skip_masked_chunks and window and causal and s == t:
-        # Only chunks whose k range intersects [q_start - window, q_end] can
-        # contribute.  With q covering [0, s) this keeps chunks where
-        # c*chunk <= s-1 and (c+1)*chunk > -window... for same-length
-        # self-attention every chunk intersects *some* query row, so the win
-        # comes from processing each query-chunk separately.  We implement the
-        # query-chunked variant below instead.
-        return _windowed_attention_qchunked(
-            q, k, v, window=window, softcap=softcap, chunk=chunk)
-
-    if remat_chunks:
-        # flash-attention-style backward: recompute each chunk's scores in
-        # the backward pass instead of saving [B,S,KH,G,C] fp32 residuals
-        # per chunk (the Pallas kernel does this natively on TPU)
-        one_chunk = jax.checkpoint(one_chunk)
-    (acc, m, l), _ = jax.lax.scan(one_chunk, (acc0, m0, l0), (kc_t, vc_t, idx),
-                                  unroll=unroll)
+    (acc, m, l), _ = jax.lax.scan(one_chunk, (acc0, m0, l0), (kc_t, vc_t, idx))
     out = acc / jnp.maximum(l[..., None], 1e-30)
     return out.reshape(b, s, h, d).astype(q.dtype)
-
-
-def _windowed_attention_qchunked(
-    q: jax.Array, k: jax.Array, v: jax.Array, *, window: int,
-    softcap: float, chunk: int,
-) -> jax.Array:
-    """Sliding-window attention that only touches the KV chunks each query
-    chunk can see: O(S * window) compute instead of O(S^2).
-
-    Requires window % chunk == 0 (or window <= chunk).  Each query chunk i
-    attends to KV span [i*chunk - window_chunks*chunk, (i+1)*chunk).
-    """
-    b, s, h, d = q.shape
-    kh = k.shape[2]
-    g = h // kh
-    chunk = min(chunk, s)
-    assert s % chunk == 0
-    w_chunks = max(1, -(-window // chunk))  # ceil
-    span = (w_chunks + 1) * chunk
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
-    n_q = s // chunk
-
-    # pad KV on the left so every span slice is in-bounds
-    pad = w_chunks * chunk
-    kp = jnp.pad(k, ((0, 0), (pad, 0), (0, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (pad, 0), (0, 0), (0, 0)))
-
-    def one_q_chunk(i):
-        qb = jax.lax.dynamic_slice_in_dim(q, i * chunk, chunk, axis=1)
-        qb = qb.reshape(b, chunk, kh, g, d).astype(jnp.float32) * scale
-        kb = jax.lax.dynamic_slice_in_dim(kp, i * chunk, span, axis=1)
-        vb = jax.lax.dynamic_slice_in_dim(vp, i * chunk, span, axis=1)
-        q_pos = i * chunk + jnp.arange(chunk)
-        k_pos = i * chunk - pad + jnp.arange(span)
-        sc = jnp.einsum("bskgd,bckd->bskgc", qb, kb.astype(jnp.float32))
-        if softcap:
-            sc = layers.softcap(sc, softcap)
-        mask = (q_pos[:, None] >= k_pos[None, :]) & \
-               (q_pos[:, None] - k_pos[None, :] < window) & \
-               (k_pos[None, :] >= 0)
-        sc = jnp.where(mask[None, :, None, None, :], sc, NEG_INF)
-        p = jax.nn.softmax(sc, axis=-1)
-        out = jnp.einsum("bskgc,bckd->bskgd", p, vb.astype(jnp.float32))
-        return out.reshape(b, chunk, h, d)
-
-    outs = jax.lax.map(one_q_chunk, jnp.arange(n_q))  # [n_q, B, chunk, H, D]
-    out = jnp.moveaxis(outs, 0, 1).reshape(b, s, h, d)
-    return out.astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -225,23 +141,14 @@ def decode_attention(
     window: int = 0,
     softcap: float = 0.0,
     k_pos: jax.Array | None = None,  # [T] per-slot positions (ring buffers)
-    lowp: bool = False,  # keep K/V in storage dtype; f32 MXU accumulation
 ) -> jax.Array:
     b, _, h, d = q.shape
     t = k_cache.shape[1]
     kh = k_cache.shape[2]
     g = h // kh
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
-    if lowp:
-        # avoid materializing an fp32 copy of the whole cache (decode is
-        # cache-bandwidth bound): bf16 operands, fp32 accumulation on the MXU
-        qf = (q.reshape(b, 1, kh, g, d).astype(jnp.float32)
-              * scale).astype(k_cache.dtype)
-        sc = jnp.einsum("bskgd,btkd->bskgt", qf, k_cache,
-                        preferred_element_type=jnp.float32)
-    else:
-        qf = q.reshape(b, 1, kh, g, d).astype(jnp.float32) * scale
-        sc = jnp.einsum("bskgd,btkd->bskgt", qf, k_cache.astype(jnp.float32))
+    qf = q.reshape(b, 1, kh, g, d).astype(jnp.float32) * scale
+    sc = jnp.einsum("bskgd,btkd->bskgt", qf, k_cache.astype(jnp.float32))
     if softcap:
         sc = layers.softcap(sc, softcap)
     if k_pos is None:
@@ -253,11 +160,7 @@ def decode_attention(
         mask &= k_pos > cur_pos - window
     sc = jnp.where(mask[None, None, None, None, :], sc, NEG_INF)
     p = jax.nn.softmax(sc, axis=-1)
-    if lowp:
-        out = jnp.einsum("bskgt,btkd->bskgd", p.astype(v_cache.dtype),
-                         v_cache, preferred_element_type=jnp.float32)
-    else:
-        out = jnp.einsum("bskgt,btkd->bskgd", p, v_cache.astype(jnp.float32))
+    out = jnp.einsum("bskgt,btkd->bskgd", p, v_cache.astype(jnp.float32))
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 

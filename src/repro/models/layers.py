@@ -2,7 +2,7 @@
 
 Parameters are nested dicts of jnp arrays.  Homogeneous layer groups are
 stacked on a leading axis and driven by ``jax.lax.scan`` (keeps the lowered
-HLO compact — essential for 512-host-device dry-run compiles).
+HLO compact, so a deep stack compiles once per period, not once per layer).
 """
 from __future__ import annotations
 

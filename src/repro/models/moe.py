@@ -46,7 +46,7 @@ def init_moe(key, d_model: int, d_ff: int, cfg: MoEConfig,
 
 
 def moe_ffn(params: PyTree, x: jax.Array, cfg: MoEConfig, *,
-            expert_spec=None, token_mask=None):
+            token_mask=None):
     """x: [B, S, d] -> (y, aux_loss).
 
     Top-k routing with per-expert capacity C = ceil(T*k/E * factor); overflow
@@ -101,15 +101,9 @@ def moe_ffn(params: PyTree, x: jax.Array, cfg: MoEConfig, *,
 
     xe = jnp.take(tokens, tok_for_slot, axis=0)           # [E*C, d]
     xe = jnp.where(filled[:, None], xe, 0).reshape(e, capacity, d)
-    if expert_spec is not None:
-        # expert-parallel layout pin: tokens land on the chips that own the
-        # experts (one all-to-all) instead of XLA's default resharding
-        xe = jax.lax.with_sharding_constraint(xe, expert_spec)
     h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, params["w_gate"])) * \
         jnp.einsum("ecd,edf->ecf", xe, params["w_up"])
     ye = jnp.einsum("ecf,efd->ecd", h, params["w_down"])  # [E, C, d]
-    if expert_spec is not None:
-        ye = jax.lax.with_sharding_constraint(ye, expert_spec)
     ye = ye.reshape(e * capacity, d) * gate_for_slot[:, None].astype(ye.dtype)
     y = jnp.zeros((n_tok, d), ye.dtype).at[tok_for_slot].add(
         jnp.where(filled[:, None], ye, 0))

@@ -109,7 +109,7 @@ def ssd_reference(x, dt, a, b, c, d_skip):
 
 
 def ssd_chunked(x, dt, a, b, c, d_skip, *, chunk: int = 128,
-                initial_state=None, unroll: bool = False):
+                initial_state=None):
     """Chunked SSD: O(S/L) sequential steps of attention-like matmuls."""
     bsz, s, h, p = x.shape
     n = b.shape[-1]
@@ -158,7 +158,7 @@ def ssd_chunked(x, dt, a, b, c, d_skip, *, chunk: int = 128,
 
     h0 = (jnp.zeros((bsz, h, n, p), jnp.float32) if initial_state is None
           else initial_state.astype(jnp.float32))
-    hfin, ys = jax.lax.scan(scan_body, h0, chunks, unroll=unroll)
+    hfin, ys = jax.lax.scan(scan_body, h0, chunks)
     y = jnp.moveaxis(ys, 0, 1).reshape(bsz, s, h, p)
     y = y + x.astype(jnp.float32) * d_skip[None, None, :, None]
     return y.astype(x.dtype), hfin
@@ -178,8 +178,7 @@ def ssd_decode_step(hstate, xt, dtt, a, bt, ct, d_skip):
 # ---------------------------------------------------------------------------
 
 def mamba_mixer(params: PyTree, x: jax.Array, cfg: SSMConfig, *,
-                chunk: int = 128, use_pallas: bool = False,
-                unroll: bool = False) -> jax.Array:
+                chunk: int = 128, use_pallas: bool = False) -> jax.Array:
     """Train/prefill path. x: [B,S,d] -> [B,S,d]."""
     bsz, s, d_model = x.shape
     di = cfg.d_inner(d_model)
@@ -197,8 +196,7 @@ def mamba_mixer(params: PyTree, x: jax.Array, cfg: SSMConfig, *,
         from repro.kernels import ops as kops
         y, _ = kops.ssd_scan(xi, dt, a, b, c, params["d_skip"], chunk=chunk)
     else:
-        y, _ = ssd_chunked(xi, dt, a, b, c, params["d_skip"], chunk=chunk,
-                           unroll=unroll)
+        y, _ = ssd_chunked(xi, dt, a, b, c, params["d_skip"], chunk=chunk)
     y = y.reshape(bsz, s, di) * jax.nn.silu(z)
     return jnp.einsum("bsf,fd->bsd", y, params["out_proj"])
 

@@ -51,16 +51,7 @@ class RunCtx:
     ssd_chunk: int = 128
     cache_len: int = 0              # prefill: total KV capacity (>= seq len)
     use_pallas: bool = False
-    skip_masked_chunks: bool = False
     remat: str = "none"             # none | full
-    unroll: bool = False            # unroll ALL scans (dry-run probes)
-    remat_attention: bool = False   # recompute attn chunks in backward
-    cache_constraint: Any = None    # decode: PartitionSpec pin for KV caches
-    decode_lowp: bool = False       # decode attn: bf16 operands, f32 accum
-    act_spec: Any = None            # sharding constraint for the residual x
-    repeat_kv: bool = False         # GQA: repeat K/V to full head count
-    head_spec: Any = None           # pin q/k/v heads to 'model' (Megatron)
-    moe_expert_spec: Any = None     # pin MoE dispatch to expert-parallel
     pages: Any = None               # paged mode: PageInfo (block tables etc.)
 
 
@@ -247,16 +238,11 @@ def _self_attn(p, x, kind: str, ctx: RunCtx, cache):
         slot = ctx.pos % length
         k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, 1)
         v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, 1)
-        if ctx.cache_constraint is not None:
-            k_cache = jax.lax.with_sharding_constraint(
-                k_cache, ctx.cache_constraint)
-            v_cache = jax.lax.with_sharding_constraint(
-                v_cache, ctx.cache_constraint)
         slot_pos = jax.lax.dynamic_update_slice_in_dim(
             cache["slot_pos"], ctx.pos[None].astype(jnp.int32), slot, 0)
         out = attention.decode_attention(
             q, k_cache, v_cache, ctx.pos, window=window,
-            softcap=cfg.attn_softcap, k_pos=slot_pos, lowp=ctx.decode_lowp)
+            softcap=cfg.attn_softcap, k_pos=slot_pos)
         new_cache = {"k": k_cache, "v": v_cache, "slot_pos": slot_pos}
     else:
         b, s, _ = x.shape
@@ -264,16 +250,6 @@ def _self_attn(p, x, kind: str, ctx: RunCtx, cache):
         pos = jnp.arange(s)[None, :]
         q = layers.apply_rope(q, pos, cfg.rope_theta)
         k = layers.apply_rope(k, pos, cfg.rope_theta)
-        if ctx.head_spec is not None and ctx.repeat_kv:
-            # Megatron-style: heads live on 'model'; scores/softmax stay
-            # chip-local, wo becomes the row-parallel matmul (one psum)
-            g_rep = cfg.n_heads // k.shape[2]
-            if g_rep > 1:
-                k = jnp.repeat(k, g_rep, axis=2)
-                v = jnp.repeat(v, g_rep, axis=2)
-            q = jax.lax.with_sharding_constraint(q, ctx.head_spec)
-            k = jax.lax.with_sharding_constraint(k, ctx.head_spec)
-            v = jax.lax.with_sharding_constraint(v, ctx.head_spec)
         if ctx.use_pallas:
             from repro.kernels import ops as kops
             out = kops.flash_attention(
@@ -281,10 +257,7 @@ def _self_attn(p, x, kind: str, ctx: RunCtx, cache):
         else:
             out = attention.chunked_attention(
                 q, k, v, causal=True, window=window,
-                softcap=cfg.attn_softcap, chunk=ctx.chunk,
-                skip_masked_chunks=ctx.skip_masked_chunks,
-                unroll=ctx.unroll, remat_chunks=ctx.remat_attention,
-                repeat_kv=ctx.repeat_kv)
+                softcap=cfg.attn_softcap, chunk=ctx.chunk)
         new_cache = None
         if ctx.mode == "prefill":
             cap = max(ctx.cache_len, s)
@@ -321,8 +294,7 @@ def apply_block(kind: str, p, x, ctx: RunCtx, cache):
         else:
             out = ssm_lib.mamba_mixer(p["mixer"], h, cfg.ssm,
                                       chunk=ctx.ssd_chunk,
-                                      use_pallas=ctx.use_pallas,
-                                      unroll=ctx.unroll)
+                                      use_pallas=ctx.use_pallas)
             new_cache = cache  # prefill state handled via chunked final state
             if ctx.mode == "prefill":
                 # recompute final state cheaply through the chunked path
@@ -369,9 +341,7 @@ def apply_block(kind: str, p, x, ctx: RunCtx, cache):
         # paged batches carry junk beyond each slot's n_valid; keep it out
         # of the capacity queues (see moe_ffn docstring)
         tm = ctx.pages.token_mask if ctx.mode == "paged" else None
-        y, aux = moe_lib.moe_ffn(p["moe"], h, cfg.moe,
-                                 expert_spec=ctx.moe_expert_spec,
-                                 token_mask=tm)
+        y, aux = moe_lib.moe_ffn(p["moe"], h, cfg.moe, token_mask=tm)
     else:
         y = layers.swiglu(h, p["mlp"]["gate"], p["mlp"]["up"], p["mlp"]["down"])
     return x + y, aux, new_cache
@@ -433,12 +403,8 @@ def _shared_attn_block(p, x, ctx: RunCtx, cache):
 def forward(params, tokens, cfg: ModelConfig, *, mode: str,
             img=None, cache=None, pos=None, chunk: int = 1024,
             ssd_chunk: int = 128, cache_len: int = 0,
-            use_pallas: bool = False,
-            skip_masked_chunks: bool = False, remat: str = "none",
-            unroll: bool = False, remat_attention: bool = False,
-            cache_constraint=None, decode_lowp: bool = False,
-            act_spec=None, repeat_kv: bool = False, head_spec=None,
-            moe_expert_spec=None, pages=None, head: bool = True):
+            use_pallas: bool = False, remat: str = "none", pages=None,
+            head: bool = True):
     """Shared driver. Returns (logits, aux_loss, new_cache).
 
     train:   tokens [B,S]   -> logits [B,S,Vp], aux, None
@@ -450,28 +416,14 @@ def forward(params, tokens, cfg: ModelConfig, *, mode: str,
     """
     ctx = RunCtx(cfg=cfg, mode=mode, pos=pos, img=img, chunk=chunk,
                  ssd_chunk=ssd_chunk, cache_len=cache_len,
-                 use_pallas=use_pallas,
-                 skip_masked_chunks=skip_masked_chunks, remat=remat,
-                 unroll=unroll, remat_attention=remat_attention,
-                 cache_constraint=cache_constraint, decode_lowp=decode_lowp,
-                 act_spec=act_spec if mode != "decode" else None,
-                 repeat_kv=repeat_kv, head_spec=head_spec,
-                 moe_expert_spec=moe_expert_spec, pages=pages)
+                 use_pallas=use_pallas, remat=remat, pages=pages)
     x = _embed(params, tokens, cfg)
-    if act_spec is not None and mode != "decode":
-        x = jax.lax.with_sharding_constraint(x, act_spec)
     aux_total = jnp.zeros((), jnp.float32)
     with_cache = mode in ("prefill", "decode", "paged")
 
     shared_p = params.get("shared_attn")
 
-    def _constrain(x):
-        if ctx.act_spec is not None:
-            x = jax.lax.with_sharding_constraint(x, ctx.act_spec)
-        return x
-
     def period_body(x, block_params, block_caches, shared_cache):
-        x = _constrain(x)
         aux_p = jnp.zeros((), jnp.float32)
         new_caches = []
         for j, kind in enumerate(cfg.period):
@@ -502,8 +454,7 @@ def forward(params, tokens, cfg: ModelConfig, *, mode: str,
         xs = (params["blocks"], cache["blocks"], shared_c)
     else:
         xs = (params["blocks"],)
-    (x, aux_total), scan_out = jax.lax.scan(scan_fn, (x, aux_total), xs,
-                                            unroll=unroll)
+    (x, aux_total), scan_out = jax.lax.scan(scan_fn, (x, aux_total), xs)
 
     new_cache: dict[str, Any] = {}
     if with_cache:

@@ -44,8 +44,7 @@ def node_leaf_spec(leaf, *, n: int, axis_name: str, lead: int = 0):
     node-stacked leaf (dim ``lead`` equals the global node count ``n``),
     replicated ``P()`` for everything else (step counters, per-stage
     scalars).  ``lead=1`` handles chunked batches stacked [k, n, ...].
-    Shared by :class:`ShardedRuntime` and the launcher's sharded step
-    builder (``launch/steps.py``) so the rule cannot drift."""
+    Used by :class:`ShardedRuntime` and, through it, the hybrid runtime."""
     shape = getattr(leaf, "shape", None)
     if shape is not None and len(shape) > lead and shape[lead] == n:
         spec = [None] * len(shape)
@@ -81,17 +80,10 @@ class ShardedRuntime(Runtime):
                 f"{axes.get(tr.node_axis)}, topology has n={n}")
         self.axis_name = tr.node_axis
         self.mesh = tr.mesh
-        # the compiled collective schedule this step executes in-place:
-        # resolve_gossip already validated mesh x topology; 'ring' (the
-        # legacy two-ppermute special case) compiles to the same schedule,
-        # and 'dense' (forced) runs every site as a local all-gather round
-        r = tr._resolved
-        if r.kind == "sparse":
-            self._schedule = r.schedule
-        elif r.kind == "dense":
-            self._schedule = None
-        else:
-            self._schedule = gossip.compile_gossip_schedule(tr.topology)
+        # the compiled collective schedule this step executes in-place
+        # (resolve_gossip already validated mesh x topology); 'dense'
+        # (forced) runs every site as a local all-gather round
+        self._schedule = tr._resolved.schedule
 
     # -- node-axis hooks ------------------------------------------------------
     def _node_rngs(self, rng, n: int):

@@ -12,7 +12,7 @@ terminal.
 
 This module also owns the repo's shared markdown-table helpers
 (:func:`markdown_table`, :func:`fmt_s`, :func:`sparkline`) —
-``launch/report.py`` builds its dry-run/roofline tables on them.
+``launch/report.py`` builds its kernels/serve benchmark tables on them.
 """
 from __future__ import annotations
 

@@ -154,11 +154,10 @@ class DecentralizedTrainer:
             # own schedule.  _resolved still carries the compiled
             # node-granular schedule so wire accounting sees the real
             # per-edge message counts.
-            if self.gossip_schedule == "ring_ppermute":
+            if self.gossip_schedule not in gossip.GOSSIP_SCHEDULES:
                 raise ValueError(
-                    "gossip_schedule='ring_ppermute' is the one-node-per-"
-                    "device special case; runtime='hybrid' uses 'auto' | "
-                    "'sparse_ppermute' | 'dense'")
+                    f"unknown gossip schedule {self.gossip_schedule!r}; "
+                    f"valid: {' | '.join(gossip.GOSSIP_SCHEDULES)}")
             if self.gossip_schedule == "dense" or self.topology.n == 1:
                 self._resolved = gossip.ResolvedGossip("dense")
             else:
@@ -166,8 +165,8 @@ class DecentralizedTrainer:
                     "sparse", gossip.compile_gossip_schedule(self.topology),
                     self.mesh, self.node_axis)
         else:
-            # one resolver for every assembly path (shared with
-            # launch/steps.py); raises eagerly on mismatches
+            # the node-granular rules of the vmap and sharded runtimes;
+            # raises eagerly on mismatches
             self._resolved = gossip.resolve_gossip(
                 self.topology, schedule=self.gossip_schedule, mesh=self.mesh,
                 node_axis=self.node_axis if self.mesh is not None else None)

@@ -1,8 +1,8 @@
 """Declarative experiment API (repro.api): spec round-trip, eager
 validation, preset smoke runs, and the bit-for-bit pin against the
 pre-refactor hand-wired quickstart — plus the satellite fixes that rode
-along (make_comm spec rejection, scanned-loop exhaustion warning,
-choose_n_nodes guard, shared gossip resolver)."""
+along (make_comm spec rejection, scanned-loop exhaustion warning, the
+gossip resolver)."""
 import json
 
 import jax
@@ -65,8 +65,11 @@ def _base(**kw):
     ({"topology": {"name": "social", "n": 16}}, "fixed n=32"),
     ({"topology": {"name": "exp", "n": 12}}, "power-of-two"),
     ({"topology": {"name": "hypercube"}}, "unknown topology"),
-    ({"gossip": {"schedule": "ring_ppermute"},
-      "topology": {"name": "exp", "n": 16}}, "ring_ppermute"),
+    # the retired legacy ring schedule: a saved spec carrying it fails loudly
+    pytest.param({"gossip": {"schedule": "ring_ppermute"},
+                  "topology": {"name": "exp", "n": 16}},
+                 "unknown schedule 'ring_ppermute'",
+                 id="updates3-ring_ppermute"),
     ({"gossip": {"schedule": "warp"}}, "unknown schedule"),
     ({"data": {"n_data": 64, "min_per_client": 4}}, "unsatisfiable"),
     ({"data": {"alpha": 0.0}}, "alpha must be > 0"),
@@ -158,7 +161,7 @@ _TINY_LM = {"kwargs": {
     "arch": "tinyllama-1.1b",
     "overrides": {"name": "llama-tiny", "n_layers": 1, "d_model": 64,
                   "n_heads": 2, "n_kv_heads": 2, "head_dim": 32,
-                  "d_ff": 128, "vocab_size": 256, "mesh_divisor": 1},
+                  "d_ff": 128, "vocab_size": 256},
     "chunk": 16}}
 
 
@@ -289,19 +292,8 @@ def test_scanned_exhaustion_warns_and_truncates():
 
 
 # ---------------------------------------------------------------------------
-# satellite: choose_n_nodes guard + shared gossip resolver
+# the gossip resolver
 # ---------------------------------------------------------------------------
-
-
-def test_choose_n_nodes_without_data_axis():
-    from repro.configs import get_config
-    from repro.launch import steps as steps_mod
-
-    mesh = jax.sharding.Mesh(
-        np.array(jax.devices()[:1]).reshape(1), ("model",))
-    cfg = get_config("tinyllama-1.1b", reduced=True)
-    with pytest.warns(UserWarning, match="no 'data' axis"):
-        assert steps_mod.choose_n_nodes(cfg, mesh) == 1
 
 
 def test_resolve_gossip_rules():
@@ -313,7 +305,7 @@ def test_resolve_gossip_rules():
     assert gossip.resolve_gossip(topology.ring(1),
                                  schedule="sparse_ppermute").kind == "dense"
     with pytest.raises(ValueError, match="needs mesh"):
-        gossip.resolve_gossip(ring4, schedule="ring_ppermute")
+        gossip.resolve_gossip(ring4, schedule="sparse_ppermute")
     with pytest.raises(ValueError, match="unknown gossip schedule"):
         gossip.resolve_gossip(ring4, schedule="warp")
     mesh1 = jax.sharding.Mesh(
@@ -324,13 +316,23 @@ def test_resolve_gossip_rules():
     with pytest.raises(ValueError, match="no axis 'nodes'"):
         gossip.resolve_gossip(ring4, schedule="sparse_ppermute", mesh=mesh1,
                               node_axis="nodes")
-    # the ring_ppermute-on-non-ring refusal is mesh-independent and is also
-    # exercised at spec time (test_validation_errors); check the resolver's
-    # own message with a 4-device host mesh when available
-    if len(jax.devices()) >= 4:
-        mesh4 = jax.sharding.Mesh(
-            np.array(jax.devices()[:4]).reshape(4), ("data",))
-        with pytest.raises(ValueError, match="ring schedule only"):
-            gossip.resolve_gossip(topology.complete(4),
-                                  schedule="ring_ppermute", mesh=mesh4,
-                                  node_axis="data")
+
+
+def test_trainer_refuses_ring_ppermute():
+    """The retired legacy ring schedule is an unknown name to the trainer on
+    every runtime: the compiled schedule covers a ring in two rounds."""
+    from repro.core import optim, topology
+    from repro.launch.mesh import make_debug_mesh
+    from repro.train import DecentralizedTrainer
+
+    def loss_fn(p, _s, b, _r):
+        return jnp.mean((b[0] @ p["w"] - b[1]) ** 2), ({}, {})
+
+    mesh1 = make_debug_mesh((1,), ("data",))
+    for runtime, mesh in (("vmap", None), ("hybrid", mesh1)):
+        with pytest.raises(ValueError,
+                           match="unknown gossip schedule 'ring_ppermute'"):
+            DecentralizedTrainer(
+                loss_fn, optim.make_optimizer("dsgd", lr=0.01),
+                topology.ring(4), mesh=mesh, runtime=runtime,
+                gossip_schedule="ring_ppermute")

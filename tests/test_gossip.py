@@ -70,33 +70,39 @@ def test_bf16_mix_stays_at_consensus():
 
 
 _SHARDMAP_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import os, sys
+n = int(sys.argv[1])
+os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
 import numpy as np
 import jax, jax.numpy as jnp
 from repro.core import gossip, topology
 from repro.launch.mesh import make_debug_mesh
 
-n = 8
-mesh = make_debug_mesh(shape=(8,), axes=("data",))
-w = jnp.asarray(topology.ring(n).w(), jnp.float32)
+mesh = make_debug_mesh(shape=(n,), axes=("data",))
+topo = topology.ring(n)
+w = jnp.asarray(topo.w(), jnp.float32)
 k = jax.random.PRNGKey(0)
 t = {"a": jax.random.normal(k, (n, 6, 4)),
      "b": jax.random.normal(jax.random.fold_in(k, 1), (n, 3))}
 
 dense = gossip.mix_dense(w, t)
-ring = gossip.mix_ring_shardmap(t, mesh=mesh, axis_name="data")
-for a, b in zip(jax.tree.leaves(dense), jax.tree.leaves(ring)):
+sparse = gossip.mix_sparse_shardmap(t, topology=topo, mesh=mesh,
+                                    axis_name="data")
+for a, b in zip(jax.tree.leaves(dense), jax.tree.leaves(sparse)):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
-print("SHARDMAP_OK")
+print("SHARDMAP_OK", gossip.compile_gossip_schedule(topo).max_rounds)
 """
 
 
-def test_ring_ppermute_equals_dense_mix():
-    """The beyond-paper ppermute schedule computes the SAME mixing as the
-    dense W einsum for a ring topology (run on 8 forced host devices)."""
+@pytest.mark.parametrize("n", [2, 3, 4, 8], ids=lambda n: f"n={n}")
+def test_ring_sparse_schedule_equals_dense_mix(n):
+    """The compiled ppermute schedule inside ``shard_map`` computes the SAME
+    mixing as the dense W einsum for ``ring(n)`` (on n forced host
+    devices), including the two smallest rings, where both neighbours are
+    one node (n=2) or every node is a neighbour (n=3), and the ring of
+    four chips the four-chip cell gossips over."""
     res = subprocess.run(
-        [sys.executable, "-c", _SHARDMAP_SCRIPT],
+        [sys.executable, "-c", _SHARDMAP_SCRIPT, str(n)],
         capture_output=True, text=True, timeout=300,
         env={**__import__("os").environ, "PYTHONPATH": "src"},
         cwd=__import__("os").path.dirname(__import__("os").path.dirname(

@@ -109,13 +109,34 @@ def test_vocab_padding_masked():
     assert float(loss) < np.log(cfg.vocab_padded) + 1.0
 
 
-def test_unroll_equivalent():
-    cfg = get_config("gemma2-27b", reduced=True)
-    params = tf.init_lm(KEY, cfg)
-    batch, _ = make_batch(cfg)
-    a = tf.train_loss(params, batch, cfg, unroll=False)
-    b = tf.train_loss(params, batch, cfg, unroll=True)
-    np.testing.assert_allclose(float(a), float(b), rtol=1e-5)
+@pytest.mark.parametrize("window,softcap", [(0, 0.0), (20, 0.0), (20, 5.0)],
+                         ids=["full", "sliding", "sliding_softcap"])
+def test_gqa_chunked_attention_matches_reference(window, softcap):
+    """``chunked_attention`` with fewer KV heads than heads (GQA, 4 query
+    heads per KV head) over several KV chunks, the last one padded, equals
+    plain softmax attention with K/V repeated to the full head count,
+    causal, with and without a sliding window, and with gemma2's tanh
+    softcap on the scores."""
+    from repro.models import attention
+    b, s, h, kh, d, chunk = 2, 50, 8, 2, 16, 16
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (b, s, h, d))
+    k = jax.random.normal(ks[1], (b, s, kh, d))
+    v = jax.random.normal(ks[2], (b, s, kh, d))
+    out = attention.chunked_attention(q, k, v, causal=True, window=window,
+                                      softcap=softcap, chunk=chunk)
+    kr, vr = jnp.repeat(k, h // kh, axis=2), jnp.repeat(v, h // kh, axis=2)
+    sc = jnp.einsum("bshd,bthd->bhst", q, kr) / np.sqrt(d)
+    if softcap:
+        sc = softcap * jnp.tanh(sc / softcap)
+    pos = jnp.arange(s)
+    mask = pos[:, None] >= pos[None, :]
+    if window:
+        mask &= pos[:, None] - pos[None, :] < window
+    sc = jnp.where(mask[None, None], sc, -jnp.inf)
+    ref = jnp.einsum("bhst,bthd->bshd", jax.nn.softmax(sc, axis=-1), vr)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_remat_equivalent():

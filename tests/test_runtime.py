@@ -407,58 +407,62 @@ def test_cross_backend_trajectory_parity():
         res.stdout[-1500:] + res.stderr[-3000:]
 
 
-_STEPS_SHARDED_SCRIPT = r"""
-import os
+_LM_SHARDED_SCRIPT = r"""
+import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-import dataclasses
 import numpy as np
-import jax, jax.numpy as jnp
-from repro.configs import get_config
-from repro.configs.base import InputShape
-from repro.launch import steps
+import jax
+from repro import api
 from repro.launch.mesh import make_debug_mesh
 
-cfg = get_config("tinyllama-1.1b", reduced=True)
-shape = InputShape("test", seq_len=16, global_batch=4, kind="train")
+arch = sys.argv[1]
 mesh = make_debug_mesh(shape=(4,), axes=("data",))
 
 
-def build(runtime):
-    sc = steps.StepConfig(cfg=cfg, shape=shape, n_nodes=4, lr=0.1,
-                          runtime=runtime, gossip_schedule="sparse_ppermute",
-                          param_dtype=jnp.float32)
-    fn = steps.build_train_step(sc, mesh=mesh, node_axis="data")
-    p = jax.tree.map(
-        lambda l: jnp.zeros(l.shape, l.dtype),
-        steps.params_shape(sc, node_stacked=True))
-    p = jax.tree.map(
-        lambda l: jax.random.normal(jax.random.PRNGKey(0), l.shape,
-                                    l.dtype) * 0.02, p)
-    o = jax.tree.map(lambda l: jnp.zeros(l.shape, l.dtype),
-                     steps.opt_state_shape(sc, p))
-    rng = np.random.default_rng(0)
-    batch = {"tokens": jnp.asarray(rng.integers(
-                 0, cfg.vocab_size, size=(4, 1, 16)), jnp.int32),
-             "labels": jnp.asarray(rng.integers(
-                 0, cfg.vocab_size, size=(4, 1, 16)), jnp.int32)}
-    with mesh:
-        return jax.jit(fn)(p, o, batch)
+def one_step(runtime, schedule, mesh):
+    spec = api.ExperimentSpec.from_dict({
+        "name": "lm", "seed": 0, "runtime": runtime,
+        "data": {"dataset": "lm_domains", "vocab": 512, "alpha": 0.1,
+                 "batch": 2, "seq_len": 16, "n_seq_per_domain": 32,
+                 "min_per_client": 4},
+        "topology": {"name": "ring", "n": 4},
+        "gossip": {"schedule": schedule},
+        "optim": {"name": "qg_dsgdm_n", "lr": 0.1, "weight_decay": 1e-4,
+                  "kwargs": {"beta": 0.9, "mu": 0.9}},
+        "loop": {"steps": 1}, "eval": {"enabled": False},
+        "model": {"name": "transformer",
+                  "kwargs": {"arch": arch, "reduced": True}},
+    })
+    ex = api.build(spec, mesh=mesh)
+    tr = ex.trainer
+    batch = tr.put_batch(next(ex.task.make_iter()))
+    state, metrics = tr.step(ex.state, batch, jax.random.PRNGKey(0))
+    return float(metrics["loss"]), jax.tree.map(np.asarray, state.params)
 
-pv, ov, lv = build("vmap")
-ps, os_, ls = build("sharded")
-np.testing.assert_allclose(float(lv), float(ls), rtol=1e-5)
-for a, b in zip(jax.tree.leaves(pv), jax.tree.leaves(ps)):
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
-print("STEPS_SHARDED_OK", float(lv), float(ls))
+lv, pv = one_step("vmap", "dense", None)
+ls, ps = one_step("sharded", "sparse_ppermute", mesh)
+np.testing.assert_allclose(lv, ls, rtol=1e-5)
+leaves_v, leaves_s = jax.tree.leaves(pv), jax.tree.leaves(ps)
+assert len(leaves_v) == len(leaves_s)
+for a, b in zip(leaves_v, leaves_s):
+    assert a.shape == b.shape
+    np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5)
+print("LM_SHARDED_OK", lv, ls)
 """
 
 
-def test_launch_steps_sharded_builder_matches_vmap():
-    """StepConfig.runtime='sharded': the launcher's whole train step runs
-    inside one shard_map and produces the same params/loss as the vmap
-    builder on a reduced arch (4 forced host devices)."""
-    res = _run_sub(_STEPS_SHARDED_SCRIPT)
-    assert "STEPS_SHARDED_OK" in res.stdout, \
+@pytest.mark.parametrize("arch", ["tinyllama-1.1b", "mamba2-130m"])
+def test_sharded_runtime_lm_matches_vmap(arch):
+    """The runtime's sharded step on a reduced language model (ring-4, the
+    compiled sparse schedule, fp32, one node per forced host device) gives
+    the same loss and parameters after one step as the vmap runtime with
+    dense gossip, both built through ``repro.api``."""
+    res = subprocess.run(
+        [sys.executable, "-c", _LM_SHARDED_SCRIPT, arch],
+        capture_output=True, text=True, timeout=600,
+        env={**os.environ, "PYTHONPATH": "src"},
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert "LM_SHARDED_OK" in res.stdout, \
         res.stdout[-1500:] + res.stderr[-3000:]
 
 
